@@ -1,9 +1,10 @@
 """bench_duty.py — the north-star duty-cycle benchmark as one command.
 
 Builds a synthetic ImageNet-Parquet store (photo-like PNGs), runs a REAL jitted
-ResNet-50 bf16 train step on whatever device is present, and measures how much
-wall time the step loop spends blocked on input (`pipeline_duty_cycle`,
-BASELINE.md methodology). Variants isolate where the host budget goes:
+ResNet-50 bf16 train step on the TPU, and measures how much wall time the step
+loop spends blocked on input (`pipeline_duty_cycle`, BASELINE.md methodology).
+It refuses to run without a TPU: a CPU number is not a duty cycle. Variants
+isolate where the host budget goes:
 
   png        PNG decode + resize transform on the host (the baseline config)
   jpeg       realistic-size (320-560px) JPEG store, scaled DCT decode to
@@ -16,7 +17,8 @@ BASELINE.md methodology). Variants isolate where the host budget goes:
 
 Emits one JSON line per variant:
   {"metric": "duty_cycle_<variant>", "examples_per_sec": ..,
-   "input_stall_fraction": .., "host_cores": .., "device": ..}
+   "input_stall_fraction": .., "host_cores": .., "device": ..,
+   "device_kind": .., "device_count": ..}
 
 Usage: python bench_duty.py [--steps 30] [--batch-size 64] [--image-size 160]
                             [--variants png,raw,png_cached] [--num-classes 1000]
@@ -105,7 +107,7 @@ def measure_kwargs(args):
 
 
 def run_variant(variant, args, png_url, raw_url, jpeg_url, tmpdir):
-    from examples.imagenet.jax_resnet_example import make_transform
+    from examples.imagenet.transform import make_transform
     from petastorm_tpu import make_reader
     from petastorm_tpu.tools.throughput import pipeline_duty_cycle
 
@@ -174,7 +176,7 @@ def measure_step_ms(step_fn, batch_size, image_size, repeats=10):
     return statistics.median(times) * 1000
 
 
-def run_sweep(args, raw_url):
+def run_sweep(args, raw_url, device_fields):
     """The duty-vs-step-cost curve on the raw store: one point per ladder
     model. Emits a JSON line per point; the curve demonstrates (or refutes)
     that the loader hides input time once the step is heavy enough — the
@@ -214,13 +216,14 @@ def run_sweep(args, raw_url):
             'batch_size': args.batch_size,
             'image_size': args.image_size,
             'steps': args.steps,
+            **device_fields,
         }
         print(json.dumps(point), flush=True)
         results.append(point)
     best = min(results, key=lambda p: p['input_stall_fraction'])
     print(json.dumps({'metric': 'duty_sweep_best', **{k: best[k] for k in
                       ('model', 'step_ms', 'input_stall_fraction', 'duty_cycle',
-                       'examples_per_sec')}}), flush=True)
+                       'examples_per_sec')}, **device_fields}), flush=True)
     return results
 
 
@@ -246,7 +249,15 @@ def main(argv=None):
     args = parser.parse_args(argv)
 
     import jax
-    device = str(jax.devices()[0].platform)
+    devices = jax.devices()
+    device = devices[0].platform
+    if device != 'tpu':
+        raise SystemExit('bench_duty.py needs a TPU; JAX found {} ({})'.format(
+            device, devices[0].device_kind))
+    from petastorm_tpu.jax.compile_cache import use_persistent_compile_cache
+    use_persistent_compile_cache(REPO_ROOT)
+    device_fields = {'device': device, 'device_kind': devices[0].device_kind,
+                     'device_count': len(devices)}
 
     tmpdir = args.keep_dir or tempfile.mkdtemp(prefix='bench_duty_')
     png_dir = os.path.join(tmpdir, 'imagenet_png')
@@ -280,7 +291,7 @@ def main(argv=None):
                             min_dim=320, max_dim=560)
 
         if args.sweep:
-            run_sweep(args, raw_url)
+            run_sweep(args, raw_url, device_fields)
             return
         for variant in variants:
             res = run_variant(variant, args, png_url, raw_url, jpeg_url, tmpdir)
@@ -290,7 +301,7 @@ def main(argv=None):
                 'input_stall_fraction': round(res.input_stall_fraction, 4),
                 'duty_cycle': round(1 - res.input_stall_fraction, 4),
                 'host_cores': os.cpu_count(),
-                'device': device,
+                **device_fields,
                 'batch_size': args.batch_size,
                 'image_size': args.image_size,
                 'steps': args.steps,

@@ -12,61 +12,24 @@ reference's reader.py:485-502) — gradient collectives ride ICI via XLA.
 from __future__ import annotations
 
 import argparse
-import zlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from petastorm_tpu import TransformSpec, make_reader
+from examples.imagenet.transform import make_transform
+from petastorm_tpu import make_reader
 from petastorm_tpu import ops
 from petastorm_tpu.jax import JaxDataLoader
 from petastorm_tpu.models import resnet50
 from petastorm_tpu.models.train import (create_train_state, make_train_step,
                                         shard_train_state)
 from petastorm_tpu.parallel import data_sharding, make_mesh
-from petastorm_tpu.unischema import UnischemaField
 
 
 # per-channel ImageNet stats in 0-255 units (normalization happens on device)
 IMAGENET_MEAN = np.array([123.675, 116.28, 103.53], np.float32)
 IMAGENET_STD = np.array([58.395, 57.12, 57.375], np.float32)
-
-
-class _LabelFromNounId(object):
-    """Batched transform, module-level (NOT a closure: process pools pickle the
-    TransformSpec into spawned workers). Images arrive already resized by the
-    decode worker (``image_resize``), so the only work left is the label
-    column."""
-
-    def __init__(self, num_classes):
-        self.num_classes = num_classes
-
-    def __call__(self, block):
-        # crc32, not hash(): labels must agree across hosts/processes
-        # (PYTHONHASHSEED randomizes hash() per interpreter)
-        labels = np.fromiter(
-            (zlib.crc32(str(n).encode()) % self.num_classes for n in block['noun_id']),
-            dtype=np.int64, count=len(block['noun_id']))
-        return {'image': block['image'], 'label': labels}
-
-
-def make_transform(image_size, num_classes):
-    """Host side: output stays uint8 — 4x fewer bytes over PCIe than the float
-    path; cast/normalize/flip run on device inside the train step
-    (petastorm_tpu.ops). ``image_resize`` fuses decode+area-resize into one
-    GIL-released native call per column (JPEG stores additionally decode at
-    ~target resolution via m/8 DCT scaling — most pixels never exist), and the
-    remaining transform is batched: no per-row Python anywhere on the image
-    path."""
-    return TransformSpec(
-        _LabelFromNounId(num_classes),
-        edit_fields=[
-            UnischemaField('image', np.uint8, (image_size, image_size, 3), None, False),
-            UnischemaField('label', np.int64, (), None, False)],
-        removed_fields=['noun_id', 'text'],
-        batched=True,
-        image_resize={'image': (image_size, image_size)})
 
 
 def device_preprocess(images, rng):
@@ -89,7 +52,7 @@ def train(dataset_url, batch_size=64, steps=100, image_size=160, num_classes=100
         cache_kwargs = {'cache_type': 'local-disk', 'cache_location': cache_location,
                         'cache_size_limit': 10 << 30, 'cache_row_size_estimate': 200 << 10}
 
-    with mesh:
+    with jax.set_mesh(mesh):
         state = shard_train_state(state, mesh)
         train_step = make_train_step(preprocess_fn=device_preprocess,
                                      preprocess_seed=seed)

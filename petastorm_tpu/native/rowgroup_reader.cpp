@@ -15,18 +15,10 @@
 #include <arrow/api.h>
 #include <arrow/c/bridge.h>
 #include <arrow/io/file.h>
-#include <arrow/util/config.h>
 #include <parquet/arrow/reader.h>
 #include <parquet/file_reader.h>
 #include <parquet/metadata.h>
 #include <parquet/properties.h>
-
-// parquet::arrow::FileReader factory/read APIs: Status + out-param in the
-// long-stable wheels (<= 22), arrow::Result returns in the newer ones the
-// original kernel targeted. Support both; a mismatch merely disables the
-// kernel (build failure -> pure-pyarrow fallback), but matching here keeps
-// the native path alive across the pyarrow versions the fleet actually runs.
-#define PSTPU_ARROW_RESULT_APIS (ARROW_VERSION_MAJOR >= 23)
 
 #include <fcntl.h>
 
@@ -121,7 +113,6 @@ void* pstpu_open(const char* path, int use_threads, long long buffer_size) {
   handle->metadata = pq_reader->metadata();
   parquet::ArrowReaderProperties arrow_props;
   arrow_props.set_use_threads(use_threads != 0);
-#if PSTPU_ARROW_RESULT_APIS
   auto maybe_reader = parquet::arrow::FileReader::Make(
       arrow::default_memory_pool(), std::move(pq_reader), arrow_props);
   if (!maybe_reader.ok()) {
@@ -129,15 +120,6 @@ void* pstpu_open(const char* path, int use_threads, long long buffer_size) {
     return nullptr;
   }
   handle->reader = std::move(*maybe_reader);
-#else
-  auto st = parquet::arrow::FileReader::Make(
-      arrow::default_memory_pool(), std::move(pq_reader), arrow_props,
-      &handle->reader);
-  if (!st.ok()) {
-    set_error(st.ToString());
-    return nullptr;
-  }
-#endif
   return handle.release();
 }
 
@@ -195,7 +177,6 @@ int pstpu_read_row_group(void* h, int row_group, const int* columns,
   }
   advise_row_group(handle, row_group, columns, n_columns);
   std::shared_ptr<arrow::Table> table;
-#if PSTPU_ARROW_RESULT_APIS
   arrow::Result<std::shared_ptr<arrow::Table>> maybe_table =
       (columns != nullptr && n_columns >= 0)
           ? handle->reader->ReadRowGroup(row_group,
@@ -206,17 +187,6 @@ int pstpu_read_row_group(void* h, int row_group, const int* columns,
     return -1;
   }
   table = *maybe_table;
-#else
-  arrow::Status read_st =
-      (columns != nullptr && n_columns >= 0)
-          ? handle->reader->ReadRowGroup(
-                row_group, std::vector<int>(columns, columns + n_columns), &table)
-          : handle->reader->ReadRowGroup(row_group, &table);
-  if (!read_st.ok()) {
-    set_error(read_st.ToString());
-    return -1;
-  }
-#endif
   // hand ownership of the decoded batches to the stream
   arrow::TableBatchReader batch_reader(*table);
   std::vector<std::shared_ptr<arrow::RecordBatch>> batches;

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 # rows per block: multiple of every dtype's sublane minimum (uint8 needs 32)
 _BLOCK_ROWS = 256
@@ -61,6 +62,13 @@ def _normalize_pallas(flat, mean_row, inv_std_row, out_dtype, interpret=False):
     )(flat, mean_row, inv_std_row)
 
 
+def _normalize_nhwc(images, mean_row, inv_std_row, out_dtype, interpret):
+    b, h, w, c = images.shape
+    out = _normalize_pallas(images.reshape(b * h, w * c), mean_row, inv_std_row,
+                            out_dtype, interpret=interpret)
+    return out.reshape(b, h, w, c)
+
+
 def _as_channel_row(values, channels, width, name):
     arr = np.asarray(values, dtype=np.float32)
     if arr.ndim == 0:
@@ -80,7 +88,10 @@ def normalize_images(images, mean, std, out_dtype=jnp.bfloat16, use_pallas=None,
         as ``images`` (e.g. 0-255 for uint8 ImageNet stats)
     :param out_dtype: output dtype (default bfloat16, the TPU matmul input type)
     :param use_pallas: force the Pallas kernel on/off; default: on when the
-        default backend is TPU, else a pure-jnp path (identical math)
+        default backend is TPU, else a pure-jnp path (identical math). Under a
+        multi-device ``jax.set_mesh`` the kernel runs per device on the batch
+        rows split over every mesh axis (``shard_map``); a sharded batch
+        outside such a context cannot take the kernel
     :param interpret: run the Pallas kernel in interpreter mode (tests)
     """
     squeeze = images.ndim == 3
@@ -100,10 +111,19 @@ def normalize_images(images, mean, std, out_dtype=jnp.bfloat16, use_pallas=None,
         use_pallas = jax.default_backend() == 'tpu'
 
     if use_pallas or interpret:
-        flat = images.reshape(b * h, w * c)
-        out = _normalize_pallas(flat, jnp.asarray(mean_row), jnp.asarray(inv_std_row),
-                                jnp.dtype(out_dtype), interpret=interpret)
-        out = out.reshape(b, h, w, c)
+        kernel = functools.partial(_normalize_nhwc, out_dtype=jnp.dtype(out_dtype),
+                                   interpret=interpret)
+        mesh = jax.sharding.get_abstract_mesh()
+        if not mesh.empty and mesh.size > 1:
+            # XLA cannot partition a Mosaic kernel: under a multi-device mesh
+            # (``jax.set_mesh``) each device normalizes its own batch rows
+            if b % mesh.size:
+                raise ValueError('batch {} does not split over the {} devices of the '
+                                 'mesh'.format(b, mesh.size))
+            rows = P(tuple(mesh.axis_names))
+            kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(rows, P(), P()),
+                                   out_specs=rows, check_vma=False)
+        out = kernel(images, jnp.asarray(mean_row), jnp.asarray(inv_std_row))
     else:
         mean_a = jnp.asarray(mean_row.reshape(w, c), jnp.float32)
         inv_a = jnp.asarray(inv_std_row.reshape(w, c), jnp.float32)

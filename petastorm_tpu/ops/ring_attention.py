@@ -24,9 +24,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from petastorm_tpu.jax.compat import shard_map
 
 _NEG_INF = -1e30
 

@@ -36,9 +36,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from petastorm_tpu.jax.compat import shard_map
 from petastorm_tpu.ops.ring_attention import _NEG_INF, _block_update
 
 

@@ -25,9 +25,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from petastorm_tpu.jax.compat import legacy_shard_map_kwargs, shard_map
 
 
 def pipeline_spmd(stage_fn, stage_params, microbatches, axis_name):
@@ -94,8 +94,7 @@ def make_pipelined_apply(mesh, stage_fn, stage_axis='stage', num_microbatches=No
     # P(stage_axis) is a pytree PREFIX: it applies to every parameter leaf
     @functools.partial(
         shard_map, mesh=mesh,
-        in_specs=(P(stage_axis), P()), out_specs=P(),
-        **legacy_shard_map_kwargs())
+        in_specs=(P(stage_axis), P()), out_specs=P())
     def _run(stacked_params, microbatches):
         # shard_map hands each stage its [1, ...] parameter slice
         return pipeline_spmd(stage_fn, _squeeze(stacked_params), microbatches,

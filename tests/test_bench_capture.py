@@ -1,83 +1,15 @@
-"""Capture hardening of the driver-facing bench entry point (bench.py):
-the duty-sweep subprocess streamer and the contention-aware run filter.
-These mechanisms decide the number of record, so they get their own tests."""
+"""Capture hardening of the host bench entry point (bench.py): the
+headline assembly and the contention-aware run filter. These mechanisms
+decide the number of record, so they get their own tests."""
 
 import json
-import subprocess
 import sys
-import textwrap
 
 import pytest
 
 sys.path.insert(0, __file__.rsplit('/tests/', 1)[0])
 
 import bench  # noqa: E402
-
-
-def _fake_sweep_cmd(body):
-    return [sys.executable, '-c', textwrap.dedent(body)]
-
-
-def test_stream_duty_sweep_captures_burst(capsys):
-    """Complete lines flushed in ONE burst must all be captured — the
-    buffered-readline implementation lost all but the first (they sat in the
-    TextIOWrapper buffer where select can't see them)."""
-    cmd = _fake_sweep_cmd("""
-        import json, sys
-        lines = [json.dumps({'metric': 'duty_sweep', 'model': 'm%d' % i,
-                             'input_stall_fraction': 0.1 * i}) for i in range(4)]
-        sys.stdout.write('\\n'.join(lines) + '\\n')
-        sys.stdout.flush()
-    """)
-    points, error = bench._stream_duty_sweep(30, cmd=cmd)
-    assert error is None
-    assert [p['model'] for p in points] == ['m0', 'm1', 'm2', 'm3']
-    out = [json.loads(ln) for ln in capsys.readouterr().out.strip().splitlines()]
-    assert [p['model'] for p in out] == ['m0', 'm1', 'm2', 'm3']
-
-
-def test_stream_duty_sweep_deadline_keeps_completed_points():
-    """A sweep that hangs mid-ladder is killed at the deadline with every
-    completed point retained and the partial state recorded."""
-    # 20s deadline: interpreter startup alone can take several seconds on the
-    # contended 1-core bench host, and the child must get its points out
-    # before the kill for the salvage assertion to mean anything
-    cmd = _fake_sweep_cmd("""
-        import json, sys, time
-        for i in range(2):
-            print(json.dumps({'metric': 'duty_sweep', 'model': 'm%d' % i,
-                              'input_stall_fraction': 0.5}), flush=True)
-        time.sleep(600)
-    """)
-    points, error = bench._stream_duty_sweep(20, cmd=cmd)
-    assert len(points) == 2
-    assert 'deadline' in error and '2 points' in error
-
-
-def test_stream_duty_sweep_reports_child_failure_with_stderr_tail():
-    cmd = _fake_sweep_cmd("""
-        import sys
-        sys.stderr.write('RuntimeError: tunnel fell over\\n')
-        sys.exit(3)
-    """)
-    points, error = bench._stream_duty_sweep(30, cmd=cmd)
-    assert points == []
-    assert 'rc=3' in error and 'tunnel fell over' in error
-
-
-def test_stream_duty_sweep_survives_chatty_stderr():
-    """>64 KiB of stderr (a chatty TPU runtime) must not deadlock the sweep —
-    stderr goes to a temp file, not an undrained pipe."""
-    cmd = _fake_sweep_cmd("""
-        import json, sys
-        sys.stderr.write('x' * 200_000)
-        sys.stderr.flush()
-        print(json.dumps({'metric': 'duty_sweep', 'model': 'm',
-                          'input_stall_fraction': 0.2}), flush=True)
-    """)
-    points, error = bench._stream_duty_sweep(30, cmd=cmd)
-    assert error is None
-    assert len(points) == 1
 
 
 def test_main_emits_headline_line(monkeypatch, capsys):
@@ -88,12 +20,9 @@ def test_main_emits_headline_line(monkeypatch, capsys):
 
     import petastorm_tpu.tools.throughput as tp
 
-    monkeypatch.setattr(bench, '_probe_tpu', lambda *a, **k: ('none', 0))
     monkeypatch.setattr(bench, '_prebuild_native', lambda: None)
     monkeypatch.setattr(bench, '_ensure_dataset', lambda url, **kw: None)
     monkeypatch.setattr(bench, '_warm', lambda url: None)
-    monkeypatch.setattr(bench, '_duty_section',
-                        lambda **kw: {'skipped': True, 'reason': 'stubbed'})
     monkeypatch.setattr(bench, '_spin_ms', lambda: 250.0)
     monkeypatch.setattr(tp, 'reader_throughput',
                         lambda *a, **k: types.SimpleNamespace(samples_per_second=5000.0))
@@ -107,7 +36,7 @@ def test_main_emits_headline_line(monkeypatch, capsys):
     assert len(rec['runs']) == 7 and len(rec['cpu_shares']) == 7
     assert len(rec['spin_ms']) == 7 and rec['host_speed_spread'] == 0.0
     assert rec['spread'] == 0.0 and rec['excluded_mad_outliers'] == []
-    assert rec['duty'] == {'skipped': True, 'reason': 'stubbed'}
+    assert 'duty' not in rec  # the chip path is chip_smoke.py, not this capture
     # default capture runs at counters level: no critical-path block
     assert rec['critical_path'] is None
     # compression knob defaults: snappy store, sweep only on request, and the
@@ -222,80 +151,3 @@ def test_spin_normalization_degenerate_inputs():
     assert bench._spin_normalized([1.0], [1.0, 2.0]) is None
     # zero spins (clock glitch): fall back to the raw median, not a crash
     assert bench._spin_normalized([10.0, 20.0, 30.0], [0.0, 0.0, 0.0]) == 20.0
-
-
-# ---------------------------------------------------------------------------
-# Persistent on-chip ledger (BENCH_ONCHIP.json)
-# ---------------------------------------------------------------------------
-
-def _use_tmp_ledger(monkeypatch, tmp_path):
-    path = str(tmp_path / 'BENCH_ONCHIP.json')
-    monkeypatch.setattr(bench, 'ONCHIP_PATH', path)
-    return path
-
-
-def test_onchip_record_and_latest_roundtrip(monkeypatch, tmp_path):
-    _use_tmp_ledger(monkeypatch, tmp_path)
-    assert bench._latest_onchip() is None
-    bench._record_onchip({'model': 'resnet152', 'step_ms': 210.0,
-                          'input_stall_fraction': 0.031, 'duty_cycle': 0.969,
-                          'examples_per_sec': 301.0, 'device': 'tpu'})
-    last = bench._latest_onchip()
-    assert last['model'] == 'resnet152'
-    assert last['recorded_utc'].endswith('Z')
-    assert last['age_days'] is not None and last['age_days'] < 1.0
-
-
-def test_onchip_ledger_bounded_and_ordered(monkeypatch, tmp_path):
-    _use_tmp_ledger(monkeypatch, tmp_path)
-    for i in range(25):
-        bench._record_onchip({'model': 'm{}'.format(i), 'examples_per_sec': float(i)})
-    doc = bench._load_onchip()
-    assert len(doc['entries']) == 20  # bounded history
-    assert bench._latest_onchip()['model'] == 'm24'  # newest last
-
-
-def test_onchip_corrupt_ledger_recovers(monkeypatch, tmp_path):
-    path = _use_tmp_ledger(monkeypatch, tmp_path)
-    with open(path, 'w') as f:
-        f.write('not json{')
-    assert bench._load_onchip() == {'entries': []}
-    bench._record_onchip({'model': 'm'})
-    assert bench._latest_onchip()['model'] == 'm'
-
-
-def test_duty_skip_line_embeds_age_stamped_onchip(monkeypatch, tmp_path, capsys):
-    """A TPU-less capture must still carry the newest committed on-chip
-    number, age-stamped, in its skip line."""
-    _use_tmp_ledger(monkeypatch, tmp_path)
-    bench._record_onchip({'model': 'resnet101', 'input_stall_fraction': 0.042,
-                          'examples_per_sec': 412.5, 'device': 'tpu'})
-    monkeypatch.setattr(bench, '_probe_tpu', lambda *a, **k: ('cpu', 1))
-    duty = bench._duty_section()
-    out = [json.loads(ln) for ln in capsys.readouterr().out.strip().splitlines()]
-    skip = [r for r in out if r.get('metric') == 'duty_sweep_skipped'][0]
-    assert skip['last_onchip']['model'] == 'resnet101'
-    assert skip['last_onchip']['age_days'] is not None
-    assert duty['skipped'] is True
-    assert duty['last_onchip']['examples_per_sec'] == 412.5
-
-
-def test_duty_section_sweeps_when_tpu_seen_early(monkeypatch, tmp_path, capsys):
-    """A TPU seen by the START-of-capture probe must trigger the sweep even
-    if the end-of-capture probe misses (opportunistic probing), and a
-    successful sweep must persist to the ledger."""
-    _use_tmp_ledger(monkeypatch, tmp_path)
-    monkeypatch.setattr(bench, '_probe_tpu', lambda *a, **k: ('none', 0))
-    point = {'metric': 'duty_sweep', 'model': 'resnet50', 'step_ms': 80.0,
-             'input_stall_fraction': 0.02, 'duty_cycle': 0.98,
-             'examples_per_sec': 800.0}
-    monkeypatch.setattr(bench, '_stream_duty_sweep',
-                        lambda *a, **k: ([point], None))
-    duty = bench._duty_section(tpu_seen_early=True)
-    assert duty['model'] == 'resnet50' and duty['meets_bar'] is True
-    last = bench._latest_onchip()
-    assert last['model'] == 'resnet50' and last['age_days'] is not None
-    # and WITHOUT the early sighting, the same probes skip
-    duty2 = bench._duty_section(tpu_seen_early=False)
-    assert duty2['skipped'] is True
-    assert duty2['last_onchip']['model'] == 'resnet50'

@@ -67,6 +67,36 @@ def test_normalize_single_image_and_validation(rng):
         normalize_images(jnp.asarray(img), np.ones(4), STD)
 
 
+def test_normalize_pallas_runs_per_device_under_mesh(rng):
+    """Regression (chip bring-up, PR 21): XLA cannot partition a Mosaic
+    kernel, so the sharded data-parallel train step failed to compile on a
+    four-chip mesh. Under ``jax.set_mesh`` the kernel now runs per device and
+    the batch stays split: no device gathers the whole batch."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(jax.devices()[:4]), ('data',))
+    images = rng.integers(0, 256, (8, 4, 16, 3), dtype=np.uint8)
+    x = jax.device_put(images, NamedSharding(mesh, P('data')))
+    with jax.set_mesh(mesh):
+        fn = jax.jit(lambda a: normalize_images(a, MEAN, STD, out_dtype=jnp.float32,
+                                                interpret=True))
+        out = fn(x)
+        hlo = fn.lower(x).compile().as_text()
+    np.testing.assert_allclose(np.asarray(out), _reference(images, MEAN, STD),
+                               rtol=1e-5, atol=1e-5)
+    shards = out.addressable_shards
+    assert len({s.device for s in shards}) == 4
+    assert all(s.data.shape[0] == 2 for s in shards)
+    assert 'all-gather' not in hlo
+
+
+def test_normalize_pallas_under_mesh_rejects_unsplittable_batch(rng):
+    from jax.sharding import Mesh
+    mesh = Mesh(np.array(jax.devices()[:4]), ('data',))
+    images = jnp.asarray(rng.integers(0, 256, (6, 4, 16, 3), dtype=np.uint8))
+    with jax.set_mesh(mesh), pytest.raises(ValueError, match='does not split'):
+        normalize_images(images, MEAN, STD, interpret=True)
+
+
 def test_normalize_jits_inside_train_step(rng):
     # the op must compose with jit (static shapes, no python control flow)
     images = jnp.asarray(rng.integers(0, 256, (2, 8, 16, 3), dtype=np.uint8))
