@@ -78,22 +78,28 @@ def prefetch_to_device(iterator, target=None, size=2, background=True):
     if size < 1:
         raise ValueError('size must be >= 1')
 
+    # ``infeed_wait`` times the consumer's wait for its next batch inside
+    # next(): the queue take, or on the synchronous path the refill that
+    # stages before the yield
     if not background:
         queue = deque()
         it = iter(iterator)
+        exhausted = False
         try:
             while True:
-                while len(queue) < size:
-                    try:
-                        batch = next(it)
+                with obs.stage('infeed_wait', cat='infeed'):
+                    while not exhausted and len(queue) < size:
+                        try:
+                            batch = next(it)
+                        except StopIteration:
+                            exhausted = True
+                            break
                         # causal tracing: when fed a JaxDataLoader (not a bare
                         # generator) the infeed span joins the batch's tree
                         with obs.use_trace(getattr(iterator, 'last_trace', None)):
                             queue.append(stage_batch(batch, target))
-                    except StopIteration:
-                        while queue:
-                            yield queue.popleft()
-                        return
+                if not queue:
+                    return
                 yield queue.popleft()
         finally:
             queue.clear()
@@ -140,7 +146,8 @@ def prefetch_to_device(iterator, target=None, size=2, background=True):
     thread.start()
     try:
         while True:
-            item = q.get()
+            with obs.stage('infeed_wait', cat='infeed'):
+                item = q.get()
             if isinstance(item, _Final):
                 if item.exc is not None:
                     raise item.exc

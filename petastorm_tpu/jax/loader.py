@@ -391,8 +391,9 @@ class JaxDataLoader(object):
             with self._state_lock:
                 # block granularity (one row group), never per row: the
                 # counters-level overhead contract of the hot loop
-                with obs.span('shuffle.add_block', cat='loader',
-                              occupancy=buffer.size):
+                with obs.stage('shuffle_add', cat='loader') as sp:
+                    if obs.spans_on():
+                        sp.annotate(occupancy=buffer.size)
                     if self._columnar_ngram:
                         buffer.add_block(_flatten_ngram_block(item))
                     else:
@@ -400,10 +401,11 @@ class JaxDataLoader(object):
                 obs.gauge_set('shuffle_buffer_occupancy', buffer.size)
 
     def _buffer_emit(self, buffer, count):
-        """One shuffle-buffer batch extraction, traced with its pre-emit
-        occupancy (spans level; block granularity)."""
-        with obs.span('shuffle.emit', cat='loader', occupancy=buffer.size,
-                      rows=count):
+        """One shuffle-buffer batch extraction, timed; at spans level traced
+        with its pre-emit occupancy (block granularity)."""
+        with obs.stage('shuffle_emit', cat='loader') as sp:
+            if obs.spans_on():
+                sp.annotate(occupancy=buffer.size, rows=count)
             return buffer.emit(count)
 
     def _emit_columnar(self, batch):
@@ -433,25 +435,31 @@ class JaxDataLoader(object):
         reader_it = iter(self.reader)
         exhausted = False
         while True:
+            # one timer a batch around the per-row pulls, adds and draws (the
+            # reader's pool_wait nests inside), never one a row
+            with obs.stage('shuffle_fill', cat='loader'):
+                exhausted = self._fill(buffer, pending, reader_it, exhausted)
             with self._state_lock:
-                batch = None
+                if len(pending) == bs or (pending and not self._drop_last):
+                    batch = self._emit(list(pending))
+                    pending.clear()
+                else:
+                    # drop_last leftovers are intentionally dropped — clear
+                    # so an exhausted loader can be iterated again
+                    pending.clear()
+                    return
+            yield batch
+
+    def _fill(self, buffer, pending, reader_it, exhausted):
+        """Draw rows into ``pending`` until it holds a batch or the reader is
+        exhausted and the buffer drained. Returns ``exhausted``."""
+        bs = self.batch_size
+        while True:
+            with self._state_lock:
                 while buffer.can_retrieve() and len(pending) < bs:
                     pending.append(buffer.retrieve())
-                if len(pending) == bs:
-                    batch = self._emit(pending)
-                    pending.clear()
-                elif exhausted:
-                    if pending and not self._drop_last:
-                        batch = self._emit(list(pending))
-                        pending.clear()
-                    else:
-                        # drop_last leftovers are intentionally dropped — clear
-                        # so an exhausted loader can be iterated again
-                        pending.clear()
-                        return
-            if batch is not None:
-                yield batch
-                continue
+                if len(pending) == bs or exhausted:
+                    return exhausted
             w0 = time.perf_counter()
             try:
                 item = next(reader_it)

@@ -142,7 +142,7 @@ class _StageTimer(object):
     the span to a context discovered only mid-flight (``pool_wait``)."""
 
     __slots__ = ('name', 'cat', 'args', '_t0', '_wall0', '_spans', '_ctx',
-                 '_link', '_sid', '_pushed', '_act', '_act_prev')
+                 '_link', '_sid', '_pushed', '_act', '_act_prev', '_annotation')
 
     def __init__(self, name, cat, args, spans):
         self.name = name
@@ -169,6 +169,7 @@ class _StageTimer(object):
                 self._pushed = True
             else:
                 self._sid = None
+        self._annotation = _trace.open_annotation(self.cat, self.name)
         self._t0 = _time.perf_counter()
         return self
 
@@ -178,26 +179,35 @@ class _StageTimer(object):
         if self._spans and ctx is not None:
             self._link = ctx
 
+    def annotate(self, **args):
+        """Add args learnt mid-flight to the event (no-op below spans level;
+        guard the call with :func:`spans_on` where the args cost work)."""
+        if self._spans:
+            self.args = dict(self.args or (), **args)
+
     def __exit__(self, exc_type, exc_value, tb):
         dur = _time.perf_counter() - self._t0
         _metrics.get_registry().stage_timer(self.name).record(dur)
         if self._act is not None:
             self._act.exit(self._act_prev)
+        args = None
         if self._spans:
             if self._pushed:
                 _trace._pop_trace()
-            _trace.record_span(
-                self.name, self.cat, self._wall0, dur,
-                _trace.stamp_trace_args(self.args, self._link or self._ctx,
-                                        self._sid))
+            args = _trace.stamp_trace_args(self.args, self._link or self._ctx, self._sid)
+            _trace.record_span(self.name, self.cat, self._wall0, dur, args)
+        if self._annotation is not None:
+            _trace.close_annotation(self._annotation, args)
         return False
 
 
 def stage(name, cat='pipeline', **args):
     """Time one execution of a named pipeline stage: accumulates the
     ``stage_<name>_s``/``stage_<name>_count`` counters and, at level
-    ``'spans'``, records a Chrome-trace event. No-op at ``'off'``. Use as a
-    context manager (PT700)."""
+    ``'spans'``, records a Chrome-trace event. While a ``jax.profiler``
+    session records, it also opens the host annotation ``<cat>.<name>``
+    (with ``args`` at ``'spans'``). No-op at ``'off'``. Use as a context
+    manager (PT700)."""
     if not _metrics.counters_on():
         return _trace._NOOP_SPAN
     return _StageTimer(name, cat, args or None, _metrics.spans_on())

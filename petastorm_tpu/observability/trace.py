@@ -20,6 +20,11 @@ the main process piggybacked on the pool's results channel (drained
 incrementally with :meth:`TraceRing.drain`), keyed by their own ``pid`` so
 Perfetto renders one track per process.
 
+The profiler bridge (docs/observability.md "On the profiler's timeline"):
+while a ``jax.profiler`` session records in this process, every stage timer
+and span also opens a host annotation ``<cat>.<name>`` on the profiler's
+timeline, whose clock the device planes share; the ring's epoch µs do not.
+
 Causal tracing (docs/observability.md "trace context"): every ventilated work
 item is minted a :class:`TraceContext` — a trace id ``'<ns>:<seq>'`` (the
 ventilator's 8-hex nonce plus the item's ventilation seq) and a parent span
@@ -38,6 +43,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque, namedtuple
@@ -125,6 +131,42 @@ def record_span(name, cat, ts_epoch_s, dur_s, args=None):
     if args:
         event['args'] = args
     _ring.add(event)
+
+
+# -- the profiler bridge -------------------------------------------------------
+
+#: ``jax.profiler.TraceAnnotation``, found on the first stage after ``jax`` is
+#: imported; None until then
+_annotation_cls = None
+
+
+def _find_annotation_cls():
+    """``jax.profiler.TraceAnnotation`` where this process has imported jax,
+    else None. Imports nothing: a process without jax cannot be under its
+    profiler, and a worker process must not pay for importing it."""
+    global _annotation_cls
+    profiler = getattr(sys.modules.get('jax'), 'profiler', None)
+    _annotation_cls = getattr(profiler, 'TraceAnnotation', None)
+    return _annotation_cls
+
+
+def open_annotation(cat, name):
+    """An entered profiler annotation ``<cat>.<name>`` while a ``jax.profiler``
+    session records in this process, else None (one ``is_enabled()`` call)."""
+    cls = _annotation_cls or _find_annotation_cls()
+    if cls is None or not cls.is_enabled():
+        return None
+    annotation = cls(cat + '.' + name)
+    annotation.__enter__()
+    return annotation
+
+
+def close_annotation(annotation, args=None):
+    """Close an annotation of :func:`open_annotation`, with ``args`` (spans
+    level only) as its metadata."""
+    if args:
+        annotation.set_metadata(**args)
+    annotation.__exit__(None, None, None)
 
 
 # -- trace-context propagation ------------------------------------------------
@@ -226,7 +268,7 @@ class _Span(object):
     identity from the frame it receives, after the span already opened)."""
 
     __slots__ = ('name', 'cat', 'args', '_t0', '_wall0', '_ctx', '_link',
-                 '_sid', '_pushed')
+                 '_sid', '_pushed', '_annotation')
 
     def __init__(self, name, cat, args):
         self.name = name
@@ -245,6 +287,7 @@ class _Span(object):
         else:
             self._sid = None
             self._pushed = False
+        self._annotation = open_annotation(self.cat, self.name)
         self._t0 = time.perf_counter()
         return self
 
@@ -258,8 +301,10 @@ class _Span(object):
         dur = time.perf_counter() - self._t0
         if self._pushed:
             _pop_trace()
-        record_span(self.name, self.cat, self._wall0, dur,
-                    stamp_trace_args(self.args, self._link or self._ctx, self._sid))
+        args = stamp_trace_args(self.args, self._link or self._ctx, self._sid)
+        record_span(self.name, self.cat, self._wall0, dur, args)
+        if self._annotation is not None:
+            close_annotation(self._annotation, args)
         return False
 
 
@@ -285,6 +330,9 @@ class _NoopSpan(object):
         return False
 
     def link(self, ctx):
+        return None
+
+    def annotate(self, **args):
         return None
 
 

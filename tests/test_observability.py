@@ -377,3 +377,65 @@ def test_spans_level_records_pipeline_stages(synthetic_dataset):
     # the worker's read+decode seconds live in ONE fused span on fused
     # stores, or in the classic read/decode pair on the Arrow path
     assert 'fused_decode' in names or {'read', 'decode'} <= names
+
+
+# ---------------------------------------------------------------------------
+# the step loop's wait and the pump thread's shuffle work, counted
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize('background', [True, False])
+def test_infeed_wait_counts_each_batch_taken(background):
+    import jax
+
+    from petastorm_tpu.jax import prefetch_to_device
+    obs.configure('counters')
+    batches = ({'x': np.full((4, 3), i, np.float32)} for i in range(10))
+    it = prefetch_to_device(batches, jax.devices()[0], size=2, background=background)
+    taken = [int(next(it)['x'][0, 0]) for _ in range(6)]
+    it.close()
+    assert taken == list(range(6))
+    counters = obs.snapshot()['counters']
+    assert counters['stage_infeed_wait_count'] == 6
+    assert counters['stage_infeed_wait_s'] >= 0.0
+    # each batch was staged exactly once, whichever thread staged it
+    assert counters['stage_infeed_count'] >= 6
+
+
+def test_shuffle_stages_are_timed_and_traced(synthetic_dataset):
+    reader = make_reader(synthetic_dataset.url, schema_fields=['id'],
+                         reader_pool_type='thread', workers_count=1,
+                         output='columnar', telemetry='counters')
+    with JaxDataLoader(reader, batch_size=20, shuffling_queue_capacity=30, seed=1) as loader:
+        assert sum(len(b['id']) for b in loader) == 100
+    counters = obs.snapshot()['counters']
+    assert counters['stage_shuffle_add_count'] == 10   # one a row group
+    assert counters['stage_shuffle_emit_count'] == 5   # one a batch
+    assert len(obs.get_ring()) == 0
+    obs.configure('spans')
+    reader = make_reader(synthetic_dataset.url, schema_fields=['id'],
+                         reader_pool_type='thread', workers_count=1,
+                         output='columnar', telemetry='spans')
+    with JaxDataLoader(reader, batch_size=20, shuffling_queue_capacity=30, seed=1) as loader:
+        assert sum(len(b['id']) for b in loader) == 100
+    events = obs.get_ring().snapshot()
+    emits = [e for e in events if e['name'] == 'shuffle_emit']
+    adds = [e for e in events if e['name'] == 'shuffle_add']
+    assert len(emits) == 5 and len(adds) == 10
+    assert all(e['cat'] == 'loader' for e in emits + adds)
+    assert [e['args']['rows'] for e in emits] == [20] * 5
+    assert all(e['args']['occupancy'] >= 20 for e in emits)
+    assert all('occupancy' in e['args'] for e in adds)
+
+
+def test_row_path_fill_is_timed_once_a_batch(synthetic_dataset):
+    reader = make_reader(synthetic_dataset.url, schema_fields=['id'],
+                         reader_pool_type='thread', workers_count=1, telemetry='counters')
+    with JaxDataLoader(reader, batch_size=20, shuffling_queue_capacity=30, seed=1) as loader:
+        assert sum(len(b['id']) for b in loader) == 100
+    counters = obs.snapshot()['counters']
+    # one a batch, and the last that finds the reader exhausted and the
+    # buffer drained; the per-row pulls, adds and draws are not timed apart
+    assert counters['stage_shuffle_fill_count'] == 6
+    assert 'stage_shuffle_add_count' not in counters
+    # the reader's pool_wait runs inside the fill, on the same thread
+    assert counters['stage_shuffle_fill_s'] >= counters['stage_pool_wait_s'] > 0.0

@@ -10,6 +10,7 @@ tests pin down.
 
 import json
 import os
+import sys
 import time
 
 import pytest
@@ -529,3 +530,150 @@ def test_jsonl_exporter_rotated_series_still_loads(tmp_path):
     live = len(path.read_text().splitlines())
     backup = len((tmp_path / 'h.jsonl.1').read_text().splitlines())
     assert len(series['snapshots']) == live + backup
+
+
+# ---------------------------------------------------------------------------
+# the profiler bridge: stages on the device trace's clock
+# ---------------------------------------------------------------------------
+
+def _program_lines(trace_dir):
+    """``{label: {line index, ...}}`` of the program's annotations in the
+    newest xplane under ``trace_dir``, as the benchmark's reduction reads
+    them."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.program_trace import program_events
+    lines = {}
+    for name, line, _, _ in program_events(trace_dir):
+        lines.setdefault(name, set()).add(line)
+    return lines
+
+
+@pytest.mark.parametrize('output,shuffle', [
+    ('columnar', ('loader.shuffle_add', 'loader.shuffle_emit')),   # a block at a time
+    ('rows', ('loader.shuffle_fill',)),                            # one timer a batch
+])
+def test_pipeline_stages_land_on_the_profiler_trace(synthetic_dataset, tmp_path, output,
+                                                    shuffle):
+    import jax
+
+    from petastorm_tpu.jax import prefetch_to_device
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    reader = make_reader(synthetic_dataset.url, schema_fields=['id', 'matrix'],
+                         reader_pool_type='thread', workers_count=2,
+                         output=output, num_epochs=1)
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with reader:
+            loader = JaxDataLoader(reader, batch_size=10, shuffling_queue_capacity=30,
+                                   seed=1)
+            taken = sum(1 for _ in prefetch_to_device(loader, size=2))
+    finally:
+        jax.profiler.stop_trace()
+    assert taken == 10
+    lines = _program_lines(str(tmp_path))
+    for label in ('worker.decode', 'loader.collate', 'infeed.infeed',
+                  'infeed.infeed_wait') + shuffle:
+        assert label in lines, sorted(lines)
+    # the consumer's wait sits on the thread that called next(); the pool's
+    # stages on the worker threads', the shuffling, collate and staging on
+    # the prefetch thread's
+    assert not lines['worker.decode'] & lines['infeed.infeed_wait']
+    assert not lines['infeed.infeed'] & lines['infeed.infeed_wait']
+    for label in ('loader.collate',) + shuffle:
+        assert lines[label] == lines['infeed.infeed']
+
+
+class _CountingAnnotation(object):
+    """Stands in for ``jax.profiler.TraceAnnotation``: counts what opens."""
+
+    enabled = False
+    opened = []
+
+    def __init__(self, label):
+        self.label = label
+        self.metadata = {}
+        _CountingAnnotation.opened.append(self)
+
+    @staticmethod
+    def is_enabled():
+        return _CountingAnnotation.enabled
+
+    def set_metadata(self, **kwargs):
+        self.metadata.update(kwargs)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.mark.parametrize('level,recording,opened', [
+    ('counters', False, 0),   # no session: one is_enabled() and nothing more
+    ('spans', False, 0),
+    ('off', True, 0),         # off: the no-op, whatever the profiler does
+    ('counters', True, 2),
+    ('spans', True, 3),       # spans level adds the trace-only span
+])
+def test_stage_opens_an_annotation_only_under_a_recording_session(monkeypatch, level,
+                                                                  recording, opened):
+    from petastorm_tpu.observability import trace as trace_mod
+    monkeypatch.setattr(trace_mod, '_annotation_cls', _CountingAnnotation)
+    monkeypatch.setattr(_CountingAnnotation, 'enabled', recording)
+    monkeypatch.setattr(_CountingAnnotation, 'opened', [])
+    obs.configure(level)
+    with obs.stage('decode', cat='worker', rows=4):
+        with obs.stage('shuffle_emit', cat='loader') as sp:
+            sp.annotate(occupancy=9)
+    with obs.span('serve.admit', cat='serve', tenant='t'):
+        pass
+    got = _CountingAnnotation.opened
+    assert len(got) == opened
+    if got:
+        assert [a.label for a in got][:2] == ['worker.decode', 'loader.shuffle_emit']
+    if level == 'spans' and recording:
+        assert got[0].metadata == {'rows': 4}
+        assert got[1].metadata == {'occupancy': 9}
+        assert got[2].label == 'serve.serve.admit'
+    elif got:
+        # below spans level the name alone: no args reach the annotation
+        assert all(a.metadata == {} for a in got)
+
+
+def test_no_session_opens_no_real_annotation(monkeypatch):
+    import jax
+
+    from petastorm_tpu.observability import trace as trace_mod
+    made = []
+
+    class Counted(jax.profiler.TraceAnnotation):
+        def __init__(self, *args, **kwargs):
+            made.append(args)
+            super().__init__(*args, **kwargs)
+
+    monkeypatch.setattr(trace_mod, '_annotation_cls', Counted)
+    obs.configure('spans')
+    with obs.stage('infeed_wait', cat='infeed'):
+        pass
+    assert made == []
+    assert obs.snapshot()['counters']['stage_infeed_wait_count'] == 1
+
+
+def test_stage_never_imports_jax_into_a_jax_free_process():
+    import subprocess
+    code = ('import sys\n'
+            'from petastorm_tpu import observability as obs\n'
+            'for level in ("counters", "spans"):\n'
+            '    obs.configure(level)\n'
+            '    with obs.stage("decode", cat="worker", rows=1):\n'
+            '        with obs.span("emit", cat="loader"):\n'
+            '            pass\n'
+            'assert obs.snapshot()["counters"]["stage_decode_count"] == 2\n'
+            'print("jax" in sys.modules)\n')
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, '-c', code], cwd=root, capture_output=True,
+                         text=True, timeout=120, check=True)
+    assert out.stdout.strip() == 'False'
