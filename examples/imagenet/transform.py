@@ -37,11 +37,13 @@ class _LabelFromNounId(object):
 def make_transform(image_size, num_classes):
     """Host side: output stays uint8 — 4x fewer bytes over PCIe than the float
     path; cast/normalize/flip run on device inside the train step
-    (petastorm_tpu.ops). ``image_resize`` fuses decode+area-resize into one
-    GIL-released native call per column (JPEG stores additionally decode at
-    ~target resolution via m/8 DCT scaling — most pixels never exist), and the
-    remaining transform is batched: no per-row Python anywhere on the image
-    path."""
+    (petastorm_tpu.ops). ``image_resize`` fuses decode and resize into one
+    GIL-released native call per row group's image column (JPEG decodes at
+    the smallest m/8 DCT scale covering the target, so most pixels never
+    exist; the resize is bilinear below 2x decimation, area at 2x or more),
+    each thread-pool worker fanning out over at most its share of the decode
+    threads (one thread at 10 workers on 13 cores), and the remaining
+    transform is batched: no per-row Python anywhere on the image path."""
     return TransformSpec(
         _LabelFromNounId(num_classes),
         edit_fields=[

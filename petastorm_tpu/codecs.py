@@ -27,6 +27,7 @@ from decimal import Decimal
 import numpy as np
 import pyarrow as pa
 
+from petastorm_tpu import observability as obs
 from petastorm_tpu.errors import SchemaError
 
 
@@ -695,22 +696,26 @@ class CompressedImageCodec(DataFieldCodec):
         the per-image allocations AND the column-stack copy of the
         ``decode_batch`` + ``stack_cells`` path), else per-image arrays stacked
         to an object column — still a single probe. ``resize=(out_h, out_w)``
-        (from ``TransformSpec.image_resize``) fuses an area resample into the
-        same native call, so every image lands pre-resized in one uniform
-        block. ``None`` defers to the generic path (nulls, unsupported flavors,
-        native codec unavailable)."""
+        (from ``TransformSpec.image_resize``) decodes and resizes the column
+        in one native call (:meth:`_decode_column_resized`). ``None`` defers
+        to the generic path (nulls, unsupported flavors, native codec
+        unavailable)."""
         from petastorm_tpu.columnar import column_cells, stack_cells
         from petastorm_tpu.native import image_codec
 
-        if column.null_count or not image_codec.is_available():
-            return None
-        cells = column_cells(column)
+        cells = None
+        if not column.null_count and image_codec.is_available():
+            cells = column_cells(column)
+        dtype = np.dtype(field.numpy_dtype)
+        if resize is not None:
+            block = self._decode_column_resized(cells, resize, dtype, min_size) if cells else None
+            # one count a column: which path served it
+            obs.count('image_columns_fallback_total' if block is None
+                      else 'image_columns_fused_total')
+            return block
         if not cells:
             return None
-        dtype = np.dtype(field.numpy_dtype)
         try:
-            if resize is not None:
-                return self._decode_column_resized(cells, resize, dtype, min_size)
             decoded = image_codec.decode_images_auto(cells, min_size=min_size)
         except (image_codec.NativeDecodeError, MemoryError):
             return None
@@ -720,39 +725,24 @@ class CompressedImageCodec(DataFieldCodec):
 
     @staticmethod
     def _decode_column_resized(cells, resize, dtype, min_size=None):
-        """Native single-probe decode (JPEG at the DCT scale covering
-        ``min_size`` — an explicit decode hint — or else the resize target),
-        then cv2 ``INTER_AREA`` per image straight into the rows of one uniform
-        ``[N, out_h, out_w(, C)]`` block — cv2's SIMD resize beats the native
-        scalar resample several-fold, so the fully-native fused path
-        (:func:`decode_images_resized`) is only used when OpenCV is absent."""
+        """Decode and resize the whole column in ONE GIL-released native call
+        (:func:`decode_images_resized`: JPEG at the DCT scale covering
+        ``min_size`` — an explicit decode hint — or else the resize target)
+        into one uniform ``[N, out_h, out_w(, C)]`` block, by the shared
+        resize policy (:func:`_resize_image`). The native resampler agrees
+        with cv2 within 1 LSB on every resize that policy picks: bilinear at
+        any ratio, area only where both axes shrink (an axis that enlarges
+        sends the image to bilinear). ``None`` — a 16-bit column, one that
+        mixes channel counts, or a cell the native decoder refuses — sends
+        the column to the per-image path."""
         from petastorm_tpu.native import image_codec
 
-        out_h, out_w = int(resize[0]), int(resize[1])
         try:
-            _import_cv2()
-        except ImportError:
-            # no SIMD resize: the fully-native fused decode+resize is faster
-            # than decode + scalar resample in two steps
-            block = image_codec.decode_images_resized(cells, resize, min_size=min_size)
-            return None if block is None else block.astype(dtype, copy=False)
-        decoded = image_codec.decode_images_auto(cells, min_size=min_size or resize)
-        if isinstance(decoded, np.ndarray):
-            if decoded.shape[1:3] == (out_h, out_w):
-                return decoded.astype(dtype, copy=False)
-            imgs = list(decoded)
-        else:
-            imgs = decoded
-        if any(img.dtype != np.uint8 for img in imgs):
-            return None  # 16-bit: per-image path handles dtype conversion
-        channels = {img.shape[2] if img.ndim == 3 else 1 for img in imgs}
-        if len(channels) != 1:
-            return None  # mixed gray/RGB cannot share one block
-        c = channels.pop()
-        out = np.empty((len(imgs), out_h, out_w) + ((c,) if c > 1 else ()), np.uint8)
-        for i, img in enumerate(imgs):
-            _resize_image(img, out_h, out_w, dst=out[i])
-        return out.astype(dtype, copy=False)
+            with obs.stage('image_decode', cat='native'):
+                block = image_codec.decode_images_resized(cells, resize, min_size=min_size)
+        except (image_codec.NativeDecodeError, MemoryError):
+            return None
+        return None if block is None else block.astype(dtype, copy=False)
 
     def decode_batch(self, field, encoded_list, min_size=None, resize=None):
         """Decode a whole column of image cells in one native call (GIL

@@ -9,11 +9,12 @@ build/load failure makes :func:`is_available` False and
 
 Threading: ``PSTPU_IMG_THREADS`` is the per-PROCESS native decode thread
 budget (default: CPU count), shared cooperatively across concurrent calls
-(:func:`_thread_grant`): a lone caller (dummy pool, benchmark, narrow reader)
-fans its column out across all idle cores, while a full worker pool's
-concurrent calls each take the free remainder (floor 1) — total decode
-threads stay ~budget instead of pool_width x budget. Pass ``threads=N``
-explicitly to bypass the accounting.
+(:func:`_thread_grant`): a lone caller (dummy pool, a script) fans its
+column out across all idle cores. A worker pool caps its own threads' share
+(:func:`set_thread_share`: a thread pool gives each worker
+``budget // width``), so its concurrent calls split the budget instead of
+each bursting over it. Pass ``threads=N`` explicitly to bypass the
+accounting.
 """
 
 from __future__ import annotations
@@ -125,17 +126,29 @@ def _default_threads():
 _budget_lock = threading.Lock()
 _threads_in_use = 0
 
+#: per-thread cap on the default fan-out (:func:`set_thread_share`); unset on
+#: threads whose owner set none
+_thread_share = threading.local()
+
+
+def set_thread_share(threads):
+    """Cap the default fan-out of native decodes made on the CALLING thread
+    at ``threads`` (``None`` lifts the cap). Set by the worker pool that owns
+    the thread, which knows how many such threads decode at once."""
+    _thread_share.threads = threads
+
 
 @contextlib.contextmanager
 def _thread_grant(requested):
     """Cooperative intra-call fan-out: ``requested=None`` (the default) takes
     whatever share of the process-wide budget is currently free (floor 1, so
-    callers always proceed) and returns it afterwards — a lone worker decoding
-    a column fans out across all idle cores, while a full worker pool's
-    concurrent calls naturally degrade to ~1 thread each instead of
-    oversubscribing cores by pool_width x budget (the failure mode the old
-    'leave PSTPU_IMG_THREADS=1 inside pools' guidance worked around). The
-    floor means N concurrent callers can transiently hold budget + (N - 1)
+    callers always proceed) and returns it afterwards — a lone caller decoding
+    a column fans out across all idle cores. A thread whose pool set a share
+    (:func:`set_thread_share`) takes at most that share: the pool already runs
+    that many calls at once, and a fan-out over the whole free budget inside
+    each would burst up to budget threads at times unrelated to the rest of
+    the process (the consumer's step loop and prefetch thread among them).
+    The floor means N concurrent callers can transiently hold budget + (N - 1)
     threads (first caller takes the free budget, later ones still get 1) —
     bounded by the pool width and accepted so callers never block on the
     grant. An explicit integer bypasses the accounting (the caller's exact
@@ -145,8 +158,11 @@ def _thread_grant(requested):
         return
     global _threads_in_use
     budget = _default_threads()
+    share = getattr(_thread_share, 'threads', None)
     with _budget_lock:
         grant = max(1, budget - _threads_in_use)
+        if share is not None:
+            grant = min(grant, share)
         _threads_in_use += grant
     try:
         yield grant

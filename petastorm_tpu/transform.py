@@ -38,11 +38,13 @@ class TransformSpec(object):
         are unaffected (no scaled decode exists for the format).
     :param image_resize: ``{field_name: (out_h, out_w)}`` — resize these image
         fields to EXACTLY that size during decode, before ``func`` runs (which
-        therefore doesn't need its own resize). The whole column decodes +
-        area-resamples in one GIL-released native call straight into a single
-        ``[N, out_h, out_w, C]`` allocation (OpenCV per-image fallback when the
-        native codec is unavailable), removing the per-row Python resize from
-        the host hot loop. Implies the scaled-JPEG-decode hint for the field.
+        therefore doesn't need its own resize). The whole column decodes and
+        resamples (bilinear below 2x decimation, area at 2x or more) in one
+        GIL-released native call straight into a single
+        ``[N, out_h, out_w, C]`` allocation (per-image fallback for 16-bit or
+        mixed gray/RGB columns, or when the native codec is unavailable),
+        removing the per-row Python resize from the host hot loop. Implies the
+        scaled-JPEG-decode hint for the field.
         The post-transform schema's shape for the field is updated
         automatically unless ``edit_fields`` overrides it.
     """

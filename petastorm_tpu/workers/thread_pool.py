@@ -28,6 +28,7 @@ import threading
 
 from petastorm_tpu import faults, observability as obs
 from petastorm_tpu.errors import EmptyResultError, WorkerTerminationRequested
+from petastorm_tpu.native import image_codec
 from petastorm_tpu.observability import blackbox
 # in-process pools speak the same canonical message-kind vocabulary as the
 # wire protocol (workers/protocol.py): results-queue records are
@@ -381,6 +382,11 @@ class ThreadPool(object):
                 if task is _RETIRE:
                     return  # deliberate slot retire (worker.shutdown in finally)
                 d, seq, args, kwargs, attempts, ctx = task
+                # the pool's workers decode at once: each takes its share of
+                # the native image-decode budget (read per item, so a resized
+                # pool re-divides it), as the process pool does at bootstrap
+                image_codec.set_thread_share(
+                    max(1, image_codec._default_threads() // self._workers_count))
                 self._tls.seq = seq
                 self._tls.dispatch = d
                 self._tls.published = False

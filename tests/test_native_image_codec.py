@@ -695,3 +695,122 @@ def test_native_resamplers_fuzz_vs_cv2():
             assert np.abs(got_a.astype(int) - ref_a.astype(int)).max() <= 1, \
                 ('area', shape, (dh, dw))
     assert checked_area >= 10  # the area contract actually got exercised
+
+
+# -- one native call per resized column, against the per-image cv2 path ------
+
+def _textured(shape, seed):
+    """Smooth gradients under noise: a photo-like texture whose JPEG decode
+    and resize exercise every filter tap."""
+    r = np.random.default_rng(seed)
+    h, w = shape[:2]
+    coarse = cv2.resize(r.normal(128, 40, (h // 4 + 1, w // 4 + 1) + shape[2:]), (w, h))
+    coarse = coarse.reshape(shape)
+    return np.clip(coarse + r.normal(0, 30, shape), 0, 255).astype(np.uint8)
+
+
+def _image_column(blobs):
+    import pyarrow as pa
+    return pa.chunked_array([pa.array(blobs, type=pa.binary())])
+
+
+def _counter(name):
+    from petastorm_tpu import observability as obs
+    return obs.snapshot().get('counters', {}).get(name, 0)
+
+
+@pytest.fixture
+def counters_level():
+    from petastorm_tpu.observability import metrics
+    level = metrics.level_name()
+    metrics.set_level('counters')
+    yield
+    metrics.set_level(level)
+
+
+@pytest.mark.parametrize('fmt,shapes,target', [
+    # the JPEG store's shape: 320-560 px decoded at the DCT scale covering
+    # 224, so mixed 240-280 px outputs at bilinear ratios
+    ('jpeg', [(320, 560, 3), (447, 333, 3), (560, 512, 3), (401, 389, 3)], (224, 224)),
+    # full-size PNG decode at area ratios, both axes shrinking 2x or more
+    ('png', [(96, 140, 3), (120, 100, 3), (77, 200, 3)], (32, 40)),
+    # area on one axis, the other shrinking under 2x
+    ('png', [(100, 50, 3), (130, 45, 3)], (40, 32)),
+    # grayscale, mixed bilinear and area ratios in one column
+    ('png', [(48, 52), (130, 90), (40, 44)], (32, 32)),
+    # an axis that enlarges sends the image to bilinear, even where the
+    # other shrinks 2x or more: area never meets an enlarging axis
+    ('png', [(100, 20, 3), (24, 90, 3)], (32, 32)),
+], ids=['jpeg-bilinear', 'png-area', 'png-area-one-axis-mild', 'gray-mixed',
+        'enlarging-axis-bilinear'])
+def test_resized_column_one_call_matches_per_image_cv2(fmt, shapes, target, counters_level):
+    """decode_column(resize=...) serves the column through one native
+    decode+resize call; the per-image path (native decode, then cv2.resize by
+    the shared policy) stays within 1 LSB and 1 - Pearson <= 1e-4 a row."""
+    channels = 3 if len(shapes[0]) == 3 else 1
+    codec = CompressedImageCodec(fmt)
+    field = UnischemaField('im', np.uint8, (None, None) + ((3,) if channels == 3 else ()),
+                           codec, False)
+    imgs = [_textured(s, seed) for seed, s in enumerate(shapes)]
+    blobs = [codec.encode(field, im) for im in imgs]
+    fused0, fallback0 = (_counter('image_columns_fused_total'),
+                         _counter('image_columns_fallback_total'))
+
+    block = codec.decode_column(field, _image_column(blobs), resize=target)
+
+    assert _counter('image_columns_fused_total') == fused0 + 1
+    assert _counter('image_columns_fallback_total') == fallback0
+    assert block.shape == (len(imgs),) + target + ((3,) if channels == 3 else ())
+    assert block.dtype == np.uint8
+    per_image = codec.decode_batch(field, blobs, resize=target)
+    for got, want in zip(block, per_image):
+        gap = np.abs(got.astype(int) - want.astype(int))
+        assert gap.max() <= 1
+        assert 1 - np.corrcoef(got.ravel(), want.ravel())[0, 1] <= 1e-4
+
+
+def test_resized_column_opens_image_decode_stage(counters_level):
+    codec = CompressedImageCodec('png')
+    field = UnischemaField('im', np.uint8, (None, None, 3), codec, False)
+    blobs = [codec.encode(field, _textured((40, 50, 3), i)) for i in range(3)]
+    before = _counter('stage_image_decode_count')
+    codec.decode_column(field, _image_column(blobs), resize=(16, 16))
+    assert _counter('stage_image_decode_count') == before + 1
+
+
+@pytest.mark.parametrize('case', ['mixed-channels', 'uint16'])
+def test_resized_column_fallback_to_per_image_path(case, counters_level):
+    """Columns the one call cannot hold in one uint8 block (mixed channel
+    counts, 16-bit) return None, count one fallback, and the per-image path
+    still delivers every image at the target size."""
+    if case == 'mixed-channels':
+        dtype = np.uint8
+        imgs = [_textured((40, 50, 3), 1), _textured((36, 44), 2)]
+    else:
+        dtype = np.uint16
+        imgs = [rng.integers(0, 65535, (40, 50, 3), dtype=np.uint16),
+                rng.integers(0, 65535, (30, 34, 3), dtype=np.uint16)]
+    codec = CompressedImageCodec('png')
+    field = UnischemaField('im', dtype, (None, None, None), codec, False)
+    blobs = [_png(im) for im in imgs]
+    fused0, fallback0 = (_counter('image_columns_fused_total'),
+                         _counter('image_columns_fallback_total'))
+
+    assert codec.decode_column(field, _image_column(blobs), resize=(16, 16)) is None
+
+    assert _counter('image_columns_fallback_total') == fallback0 + 1
+    assert _counter('image_columns_fused_total') == fused0
+    per_image = codec.decode_batch(field, blobs, resize=(16, 16))
+    assert [im.shape[:2] for im in per_image] == [(16, 16)] * len(imgs)
+    assert all(im.dtype == dtype for im in per_image)
+
+
+def test_unresized_column_counts_nothing(counters_level):
+    codec = CompressedImageCodec('png')
+    field = UnischemaField('im', np.uint8, (None, None, 3), codec, False)
+    blobs = [codec.encode(field, _textured((20, 24, 3), i)) for i in range(2)]
+    fused0, fallback0 = (_counter('image_columns_fused_total'),
+                         _counter('image_columns_fallback_total'))
+    assert codec.decode_column(field, _image_column(blobs)).shape == (2, 20, 24, 3)
+    assert (_counter('image_columns_fused_total'),
+            _counter('image_columns_fallback_total')) == (fused0, fallback0)

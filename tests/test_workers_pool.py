@@ -843,3 +843,175 @@ def test_process_pool_divides_image_thread_budget(monkeypatch):
     _, value = pool.get_results()
     pool.stop(); pool.join()
     assert value == '7'  # explicit pin inherited as-is
+
+
+# -- native image decode: a pool worker takes its share of the thread budget --
+
+class _FanoutSpy(object):
+    """Stands in for the native library: records the ``threads`` value each
+    decode+resize call receives and forwards the call."""
+
+    def __init__(self, lib):
+        self._lib = lib
+        self.fanouts = []
+
+    def __getattr__(self, name):
+        return getattr(self._lib, name)
+
+    def pstpu_img_decode_resize_batch(self, n, datas, lens, outs, infos, threads, *rest):
+        self.fanouts.append(threads)
+        return self._lib.pstpu_img_decode_resize_batch(n, datas, lens, outs, infos, threads,
+                                                       *rest)
+
+
+def _png_column(n=3):
+    import numpy as np
+    import pyarrow as pa
+    from petastorm_tpu.codecs import CompressedImageCodec
+    from petastorm_tpu.unischema import UnischemaField
+    codec = CompressedImageCodec('png')
+    field = UnischemaField('im', np.uint8, (None, None, 3), codec, False)
+    rng = np.random.default_rng(5)
+    blobs = [codec.encode(field, rng.integers(0, 255, (30 + i, 40, 3), dtype=np.uint8))
+             for i in range(n)]
+    return codec, field, pa.chunked_array([pa.array(blobs, type=pa.binary())])
+
+
+class _ResizedColumnWorker(IdentityWorker):
+    """Decodes a resized PNG column per item, as a reader's row worker does."""
+
+    def process(self, value):
+        codec, field, column = _png_column()
+        block = codec.decode_column(field, column, resize=(16, 16))
+        self.publish((value, block.shape))
+
+
+@pytest.fixture
+def fanout_spy(monkeypatch):
+    from petastorm_tpu.native import image_codec
+    if not image_codec.is_available():
+        pytest.skip('native image codec not built')
+    spy = _FanoutSpy(image_codec._load_library())
+    monkeypatch.setattr(image_codec, '_lib', spy)
+    # a budget above 1, so a granted fan-out is told apart from the floor
+    monkeypatch.setattr(image_codec, '_default_threads', lambda: 4)
+    return spy
+
+
+@pytest.mark.parametrize('width, share', [(2, 2), (3, 1), (10, 1)])
+def test_thread_pool_worker_decodes_with_its_share(width, share, fanout_spy):
+    """Each worker of a thread pool fans out over ``budget // width`` at most
+    (budget 4 here): a narrow pool keeps a fan-out, a wide one decodes on the
+    worker's own thread."""
+    pool = ThreadPool(width)
+    pool.start(_ResizedColumnWorker)
+    for i in range(6):
+        pool.ventilate(i)
+    results = _drain(pool)
+    pool.stop(); pool.join()
+    assert sorted(v for v, _ in results) == list(range(6))
+    assert all(shape == (3, 16, 16, 3) for _, shape in results)
+    assert fanout_spy.fanouts == [share] * 6
+
+
+@pytest.mark.parametrize('caller', ['own-thread', 'dummy-pool', 'one-worker-pool'])
+def test_lone_caller_keeps_thread_grant(caller, fanout_spy):
+    """Callers on their own (a script, the dummy pool, a pool of one worker)
+    still fan the call out over the free budget."""
+    if caller == 'own-thread':
+        codec, field, column = _png_column()
+        codec.decode_column(field, column, resize=(16, 16))
+    else:
+        pool = DummyPool() if caller == 'dummy-pool' else ThreadPool(1)
+        pool.start(_ResizedColumnWorker)
+        pool.ventilate(0)
+        assert len(_drain(pool)) == 1
+        pool.stop(); pool.join()
+    assert fanout_spy.fanouts == [4]
+
+
+def test_explicit_threads_win_inside_pool_worker(fanout_spy):
+    from petastorm_tpu.columnar import column_cells
+    from petastorm_tpu.native import image_codec
+
+    class ExplicitThreadsWorker(IdentityWorker):
+        def process(self, value):
+            _, _, column = _png_column()
+            image_codec.decode_images_resized(column_cells(column), (16, 16), threads=3)
+            self.publish(value)
+
+    pool = ThreadPool(2)
+    pool.start(ExplicitThreadsWorker)
+    pool.ventilate(0)
+    assert _drain(pool) == [0]
+    pool.stop(); pool.join()
+    assert fanout_spy.fanouts == [3]
+
+
+def test_thread_pool_share_follows_resized_pool(fanout_spy):
+    """The share is read per item: after the pool grows, its workers divide
+    the budget by the new width."""
+    pool = ThreadPool(2)
+    pool.start(_ResizedColumnWorker)
+    pool.ventilate(0)
+    assert len(_drain(pool)) == 1
+    pool.add_worker_slot()
+    pool.add_worker_slot()
+    pool.ventilate(1)
+    assert len(_drain(pool)) == 1
+    pool.stop(); pool.join()
+    assert fanout_spy.fanouts == [2, 1]
+
+
+@pytest.mark.parametrize('pool_type, grant', [('thread', 2), ('dummy', 4)])
+def test_fused_reader_image_decode_takes_pool_share(tmp_path, monkeypatch, pool_type, grant):
+    """The fused row-group reader (fixed-shape image column, no resize) takes
+    the same share as every other native decode made on a pool worker."""
+    import contextlib
+
+    import numpy as np
+
+    from petastorm_tpu import make_reader
+    from petastorm_tpu import native
+    from petastorm_tpu import observability as obs
+    from petastorm_tpu.codecs import CompressedImageCodec, ScalarCodec
+    from petastorm_tpu.etl.dataset_metadata import write_petastorm_dataset
+    from petastorm_tpu.native import image_codec
+    from petastorm_tpu.observability import metrics
+    from petastorm_tpu.unischema import Unischema, UnischemaField
+    if not (native.is_available() and image_codec.is_available()):
+        pytest.skip('native kernels not built')
+    monkeypatch.setattr(image_codec, '_default_threads', lambda: 4)
+    grants = []
+    real_grant = image_codec._thread_grant
+
+    @contextlib.contextmanager
+    def grant_spy(requested):
+        with real_grant(requested) as g:
+            grants.append(g)
+            yield g
+
+    monkeypatch.setattr(image_codec, '_thread_grant', grant_spy)
+    schema = Unischema('I', [
+        UnischemaField('img', np.uint8, (8, 10, 3), CompressedImageCodec('png'), False),
+        UnischemaField('id', np.int32, (), ScalarCodec(), False),
+    ])
+    url = 'file://' + str(tmp_path / 'store')
+    rng = np.random.default_rng(3)
+    rows = [{'img': rng.integers(0, 255, (8, 10, 3), np.uint8), 'id': i} for i in range(20)]
+    write_petastorm_dataset(url, schema, iter(rows), rows_per_row_group=5)
+    level = metrics.level_name()
+    metrics.set_level('counters')
+    try:
+        before = obs.snapshot().get('counters', {}).get('fused_batches_total', 0)
+        with make_reader(url, reader_pool_type=pool_type, workers_count=2,
+                         shuffle_row_groups=False) as reader:
+            got = {int(r.id): r.img for r in reader}
+        fused = obs.snapshot().get('counters', {}).get('fused_batches_total', 0) - before
+    finally:
+        metrics.set_level(level)
+    assert sorted(got) == list(range(20))
+    for r in rows:
+        np.testing.assert_array_equal(got[r['id']], r['img'])  # png is lossless
+    assert fused == 4  # every row group came through the fused reader
+    assert grants == [grant] * 4
