@@ -2,11 +2,14 @@
 reduction and the comparison that decides ``correct``.
 
 The path driven is the one ``README.md`` documents for training on a mesh:
-``make_reader`` -> ``JaxDataLoader`` -> ``prefetch_to_device`` onto
-``data_sharding(make_mesh(('data',)))`` -> the AOT-compiled
-``make_train_step(preprocess_fn=device_preprocess)`` on ResNet in bfloat16,
-which donates its state. The mesh spans the cell's chips and is entered
-with ``jax.set_mesh`` on one chip too.
+the store's feed (``benchmark/stores/<store>.py``) -> ``prefetch_to_device``
+onto ``data_sharding(make_mesh(('data',)))`` -> the AOT-compiled train step
+of the configuration's model module (``benchmark/models/<arch>.py``), which
+donates its state. The mesh spans the cell's chips and is entered with
+``jax.set_mesh`` on one chip too. What belongs to one configuration (the
+records, the feed and its check, the state, the step, the batch it takes,
+the plain reference, the throughput) is found through those two modules;
+this file holds what every configuration shares.
 """
 
 from __future__ import annotations
@@ -20,7 +23,7 @@ import time
 
 import numpy as np
 
-from benchmark import manifest
+from benchmark import gaps, manifest
 
 #: how many of the window's first steps a sampled batch is drawn from
 SAMPLE_SPAN = 40
@@ -47,7 +50,7 @@ def devices_for(cell, require_tpu=True):
     return devices
 
 
-def build_store(cell, root):
+def build_store(cell, store_module, root):
     from benchmark.stores.common import build_store as build
     t0 = time.perf_counter()
     path = build(manifest.store_path(cell.config, root), cell.config,
@@ -55,41 +58,12 @@ def build_store(cell, root):
     files = [os.path.join(d, f) for d, _, fs in os.walk(path) for f in fs
              if f.endswith('.parquet')]
     size = sum(os.path.getsize(f) for f in files)
+    records = store_module.records(cell.config)
     log('store {}: {} records, {} bytes in parquet files, {:.1f} bytes a record, '
         'ready in {:.2f} s; host cpu_count {}'.format(
-            os.path.basename(path), cell.config['images'], size,
-            size / cell.config['images'], time.perf_counter() - t0, os.cpu_count()))
+            os.path.basename(path), records, size, size / records,
+            time.perf_counter() - t0, os.cpu_count()))
     return path
-
-
-def make_model(model):
-    import jax.numpy as jnp
-
-    from petastorm_tpu.models.resnet import BottleneckBlock, ResNet
-    return ResNet(stage_sizes=model['stage_sizes'], block_cls=BottleneckBlock,
-                  num_filters=model['num_filters'], num_classes=model['num_classes'],
-                  dtype=jnp.dtype(model['dtype']))
-
-
-def make_state(config, seed, mesh):
-    """The program's TrainState holding the benchmark's weights for ``seed``,
-    made on the mesh in one jitted call."""
-    import jax
-    import optax
-
-    from benchmark import reference
-    from petastorm_tpu.models.train import TrainState, state_shardings
-    model = make_model(config['model'])
-    opt = config['optimizer']
-    tx = optax.sgd(opt['learning_rate'], momentum=opt['momentum'])
-
-    def build(key_data):
-        params, stats = reference.init_variables(config['model'], config['init'], key_data)
-        return TrainState.create(apply_fn=model.apply, params=params, batch_stats=stats, tx=tx)
-
-    key_data = reference.seed_key(seed)
-    shardings = state_shardings(jax.eval_shape(build, key_data), mesh)
-    return jax.jit(build, out_shardings=shardings)(key_data)
 
 
 def compile_step(step, *args):
@@ -110,12 +84,6 @@ def compile_step(step, *args):
     finally:
         jax.monitoring.unregister_event_listener(on_event)
     return compiled, seconds, len(hits)
-
-
-def first_gradient(opt_state):
-    """The gradient of the first step, as SGD with momentum keeps it: its
-    trace, which starts at zero, equals the first gradient after one step."""
-    return opt_state[0].trace
 
 
 def _counters():
@@ -144,9 +112,9 @@ def run(cell, seed, seconds, trace, t_start, root=manifest.ROOT, require_tpu=Tru
     """One run of ``cell``. Returns the result line as a dict.
 
     ``faults`` (tests and ``benchmark/readings.py`` only) is a dict of
-    planted faults: ``'step'`` replaces the train step factory (the control
-    is put in the program's place so too), ``'batches'`` wraps the device
-    batch iterator.
+    planted faults: ``'step'`` replaces the train step factory the model
+    module calls (the control is put in the program's place so too),
+    ``'batches'`` wraps the device batch iterator.
     ``readings(**facts)`` (``benchmark/readings.py`` only) is called after the
     comparison with what it compared; its dict goes into the line under
     ``readings``."""
@@ -154,22 +122,19 @@ def run(cell, seed, seconds, trace, t_start, root=manifest.ROOT, require_tpu=Tru
 
     faults = faults or {}
     devices = devices_for(cell, require_tpu)[:cell.chips]
-    store = build_store(cell, root)
+    config, traffic = cell.config, cell.traffic
+    store_module = manifest.load_module(manifest.store_path(config, root))
+    model = manifest.model_module(config, root)
+    store = build_store(cell, store_module, root)
 
     import jax.numpy as jnp
 
-    from benchmark import reference
-    from examples.imagenet.jax_resnet_example import device_preprocess
-    from petastorm_tpu import make_reader
-    from petastorm_tpu.jax import JaxDataLoader, prefetch_to_device
+    from petastorm_tpu import native
+    from petastorm_tpu.jax import prefetch_to_device
     from petastorm_tpu.jax.compile_cache import use_persistent_compile_cache
-    from petastorm_tpu.models.train import make_train_step
     from petastorm_tpu.parallel import data_sharding, make_mesh
 
-    config, traffic = cell.config, cell.traffic
     cache_dir = use_persistent_compile_cache(root)
-    store_module = manifest.load_module(manifest.store_path(config, root))
-    size = config['image_size']
     global_batch = traffic['batch_per_chip'] * cell.chips
     check_steps = config['check_steps']
     rng = np.random.default_rng(seed)
@@ -178,32 +143,21 @@ def run(cell, seed, seconds, trace, t_start, root=manifest.ROOT, require_tpu=Tru
 
     mesh = make_mesh(('data',), devices=devices)
     with jax.set_mesh(mesh):
-        state = make_state(config, seed, mesh)
-        make_step = faults.get('step', make_train_step)
-        step = make_step(preprocess_fn=device_preprocess,
-                         preprocess_seed=config['model']['augment_seed'])
+        state = model.make_state(config, seed, mesh)
+        step = model.make_step(config, faults.get('step'))
         sharding = data_sharding(mesh)
+        spec = model.batch_spec(config, global_batch)
+        keys = [key for key, _, _ in spec]
         compiled, compile_s, hits = compile_step(
-            step, state,
-            jax.ShapeDtypeStruct((global_batch, size, size, 3), jnp.uint8, sharding=sharding),
-            jax.ShapeDtypeStruct((global_batch,), jnp.int32, sharding=sharding))
-        from petastorm_tpu import native
-        from petastorm_tpu.native import image_codec
-        log('step compiled in {:.2f} s ({} compile-cache hits, cache {}); Pallas kernel '
-            'in step: {}; native row-group kernel: {}; native image codec: {}'.format(
+            step, state, *[jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+                           for _, shape, dtype in spec])
+        log('step compiled in {:.2f} s ({} compile-cache hits, cache {}); custom kernel '
+            'in step: {}; native row-group kernel: {}'.format(
                 compile_s, hits, cache_dir, 'tpu_custom_call' in compiled.as_text(),
-                native.is_available(), image_codec.is_available()))
+                native.is_available()))
         start = jax.device_get(state.params)
 
-        reader = make_reader('file://' + store, num_epochs=None, seed=seed,
-                             shuffle_row_groups=traffic['shuffle_row_groups'],
-                             reader_pool_type=traffic['reader_pool_type'],
-                             workers_count=traffic['workers_count'],
-                             transform_spec=store_module.transform(config))
-        with reader:
-            loader = JaxDataLoader(reader, global_batch,
-                                   shuffling_queue_capacity=traffic['shuffling_queue_capacity'],
-                                   seed=seed)
+        with store_module.feed(store, config, traffic, seed, global_batch) as loader:
             batches = prefetch_to_device(loader, sharding, size=traffic['prefetch'])
             if 'batches' in faults:
                 batches = faults['batches'](batches)
@@ -214,24 +168,26 @@ def run(cell, seed, seconds, trace, t_start, root=manifest.ROOT, require_tpu=Tru
                 for k in range(check_steps):
                     batch = next(batches)
                     kept.append(batch)
-                    state, metrics = compiled(state, batch['image'], batch['label'])
+                    state, metrics = compiled(state, *[batch[key] for key in keys])
                     check_losses.append(metrics['loss'])
                     if k == 0:
-                        grad1 = jax.device_get(first_gradient(state.opt_state))
+                        grad1 = jax.device_get(model.first_gradient(config, state))
                 end = jax.device_get(state.params)
                 check_losses = [float(v) for v in check_losses]
                 for _ in range(traffic['warmup_steps']):
                     batch = next(batches)
-                    state, metrics = compiled(state, batch['image'], batch['label'])
+                    state, metrics = compiled(state, *[batch[key] for key in keys])
                 setup_s = time.perf_counter() - t_start
-                state, sampled = _window(out, compiled, state, batches, metrics, seconds,
+                state, sampled = _window(out, compiled, keys, state, batches, metrics, seconds,
                                          samples, trace, cell, seed, root)
                 kept.extend(sampled)
             finally:
                 batches.close()
-        out.window['chips'] = cell.chips
-        out.window['global_batch'] = global_batch
-        out.window['image_size'] = size
+        w = out.window
+        w['chips'] = cell.chips
+        w['global_batch'] = global_batch
+        w['examples'] = w['steps'] * global_batch
+        w['units'] = model.units(w.pop('step_metrics'), global_batch)
         # a TPU holds the programs' temporaries in memory it reserves apart
         # from the buffers it allocates: the peak is the sum of both peaks
         stats = [d.memory_stats() or {} for d in devices]
@@ -243,26 +199,21 @@ def run(cell, seed, seconds, trace, t_start, root=manifest.ROOT, require_tpu=Tru
             json.dumps({k: getattr(analysis, k, None) for k in (
                 'argument_size_in_bytes', 'output_size_in_bytes', 'alias_size_in_bytes',
                 'temp_size_in_bytes', 'generated_code_size_in_bytes')})))
-        delivered = [(np.asarray(jax.device_get(b['record_id'])),
-                      np.asarray(jax.device_get(b['image'])),
-                      np.asarray(jax.device_get(b['label']))) for b in kept]
+        delivered = {key: np.concatenate([np.asarray(jax.device_get(b[key])) for b in kept])
+                     for key in kept[0]}
         del state, compiled, kept, batch, metrics
 
     # the comparison, with the program's state freed
     t0 = time.perf_counter()
-    ids = np.concatenate([d[0] for d in delivered])
-    ref_images, ref_labels = store_module.reference(store, ids, config)
-    delivered_images = np.concatenate([d[1] for d in delivered])
-    delivered_labels = np.concatenate([d[2] for d in delivered])
-    # the reference step takes the batch the step took, with the labels of
-    # the stored records; the images are held to the plain decode above
+    numbers, ref_inputs = store_module.check(store, config, delivered)
+    # the reference step takes the check steps' rows of the inputs the
+    # store's check gives: the delivered batches, held to the store
     rows = [slice(i * global_batch, (i + 1) * global_batch) for i in range(check_steps)]
-    ref_batches = [(delivered_images[r], ref_labels[r]) for r in rows]
-    ref = reference.train(config, seed, ref_batches, device=devices[0])
+    ref_batches = [tuple(a[r] for a in ref_inputs) for r in rows]
+    ref = model.reference(config, seed, ref_batches, devices[0])
     program = {'losses': check_losses, 'grad1': grad1,
                'change': jax.tree_util.tree_map(lambda a, b: np.float64(a) - b, end, start)}
-    numbers = compare(program, ref, delivered_images, ref_images, delivered_labels,
-                      ref_labels)
+    numbers.update(gaps.training(program, ref))
     log('reference compared in {:.2f} s; worst leaves: first gradient {} {!r}, change {} '
         '{!r}'.format(time.perf_counter() - t0, numbers.pop('_grad1_leaf'),
                       numbers['grad1_worst_gap'], numbers.pop('_change_leaf'),
@@ -272,58 +223,23 @@ def run(cell, seed, seconds, trace, t_start, root=manifest.ROOT, require_tpu=Tru
     if readings is not None:
         out.readings = dict(readings(
             config=config, seed=seed, program=program, ref=ref, ref_batches=ref_batches,
-            delivered_images=delivered_images, ref_images=ref_images,
-            delivered_labels=delivered_labels, ref_labels=ref_labels, device=devices[0],
-            store=store, store_module=store_module, ids=ids),
-            program=numbers)
+            delivered=delivered, device=devices[0], store=store, store_module=store_module,
+            model=model), program=numbers)
     correct = (all(c['value'] <= c['limit'] for c in out.checks.values())
                and out.failed == 0 and out.window['steps'] > 0)
-    return result_line(cell, out, correct, setup_s, memory_peak, trace, root)
+    return result_line(cell, model, out, correct, setup_s, memory_peak, trace, root)
 
 
-def compare(program, ref, delivered_images, ref_images, delivered_labels, ref_labels):
-    """Every number the configurations compare; each configuration holds
-    those it gives a limit. ``loss_gap`` is the largest relative gap of the
-    first steps' losses and ``loss1_gap`` that of the first step's;
-    ``grad1_gap`` and ``change_gap`` are the median leaf's gap of norms of
-    the first gradient and of the parameters' change over the first steps,
-    ``grad1_worst_gap`` and ``change_worst_gap`` the worst leaf's, and
-    ``grad1_diff`` and ``change_diff`` the median leaf's norm of the
-    difference."""
-    from benchmark import reference
-    grad1, grad1_worst, grad1_leaf = reference.leaf_gaps(program['grad1'], ref['grad1'])
-    change, change_worst, change_leaf = reference.leaf_gaps(program['change'], ref['change'])
-    a = delivered_images.reshape(len(delivered_images), -1).astype(np.float64)
-    b = ref_images.reshape(len(ref_images), -1).astype(np.float64)
-    a -= a.mean(axis=1, keepdims=True)
-    b -= b.mean(axis=1, keepdims=True)
-    correlation = (a * b).sum(axis=1) / np.sqrt((a * a).sum(axis=1) * (b * b).sum(axis=1))
-    return {
-        'loss_gap': reference.loss_gap(program['losses'], ref['losses']),
-        'loss1_gap': reference.loss_gap(program['losses'][:1], ref['losses'][:1]),
-        'grad1_gap': grad1,
-        'grad1_diff': reference.leaf_difference(program['grad1'], ref['grad1']),
-        'change_diff': reference.leaf_difference(program['change'], ref['change']),
-        'change_gap': change,
-        'grad1_worst_gap': grad1_worst,
-        'change_worst_gap': change_worst,
-        'pixel_gap': float(np.max(1.0 - correlation)),
-        'pixel_errors': int(np.sum(np.any(delivered_images != ref_images, axis=(1, 2, 3)))),
-        'label_errors': int(np.sum(delivered_labels != ref_labels)),
-        '_grad1_leaf': grad1_leaf,
-        '_change_leaf': change_leaf,
-    }
-
-
-def _steps(compiled, state, batches, metrics, done, samples=()):
+def _steps(compiled, keys, state, batches, metrics, done, samples=()):
     """Steps from a step boundary until ``done(steps, seconds)``, then until
-    the last step dispatched is ready. Each step's completion is observed as
-    a loop that logs its loss does: dispatch step i, then wait for step
-    i-1's loss. Returns ``(state, facts)``."""
+    the last step dispatched is ready. Each step takes the batch's ``keys``
+    in order. Its completion is observed as a loop that logs its loss does:
+    dispatch step i, then wait for step i-1's loss. Each step's metrics are
+    kept on the device. Returns ``(state, facts)``."""
     import jax
 
     jax.block_until_ready(metrics['loss'])
-    sampled, losses = [], []
+    sampled, step_metrics = [], []
     wait_s = 0.0
     t0 = time.perf_counter()
     completions = [t0]
@@ -335,10 +251,10 @@ def _steps(compiled, state, batches, metrics, done, samples=()):
             batch = next(batches)
             wait_s += time.perf_counter() - w0
         with jax.profiler.TraceAnnotation('dispatch_step'):
-            state, metrics = compiled(state, batch['image'], batch['label'])
+            state, metrics = compiled(state, *[batch[key] for key in keys])
         if i in samples:
             sampled.append(batch)
-        losses.append(metrics['loss'])
+        step_metrics.append(metrics)
         if prev is not None:
             prev.block_until_ready()
             completions.append(time.perf_counter())
@@ -348,13 +264,13 @@ def _steps(compiled, state, batches, metrics, done, samples=()):
             break
     prev.block_until_ready()
     completions.append(time.perf_counter())
-    return state, {'seconds': completions[-1] - t0, 'steps': i, 'losses': losses,
+    return state, {'seconds': completions[-1] - t0, 'steps': i, 'step_metrics': step_metrics,
                    'intervals': np.diff(completions).tolist(), 'loader_wait_s': wait_s,
                    'sampled': sampled, 'metrics': metrics}
 
 
-def _window(out, compiled, state, batches, metrics, seconds, samples, trace, cell, seed,
-            root):
+def _window(out, compiled, keys, state, batches, metrics, seconds, samples, trace, cell,
+            seed, root):
     """The measured window of ``seconds``. With ``trace``, a traced window of
     the traffic's ``trace_steps`` steps follows it: the profiler stalls the
     host's dispatch for most of a second every few steps (``PERF.md``), so
@@ -362,10 +278,11 @@ def _window(out, compiled, state, batches, metrics, seconds, samples, trace, cel
     import jax
 
     before = _counters()
-    state, w = _steps(compiled, state, batches, metrics,
+    state, w = _steps(compiled, keys, state, batches, metrics,
                       lambda steps, elapsed: elapsed >= seconds, samples)
     after = _counters()
-    losses = np.asarray(jax.device_get(w.pop('losses')))
+    w['step_metrics'] = jax.device_get(w['step_metrics'])
+    losses = np.asarray([m['loss'] for m in w['step_metrics']])
     out.failed = int(np.sum(~np.isfinite(losses)))
     out.counters = _delta(after, before)
     sampled = w.pop('sampled')
@@ -382,7 +299,7 @@ def _window(out, compiled, state, batches, metrics, seconds, samples, trace, cel
         try:
             with jax.profiler.TraceAnnotation(trace_reduce.WINDOW):
                 steps = cell.traffic['trace_steps']
-                state, _ = _steps(compiled, state, batches, metrics,
+                state, _ = _steps(compiled, keys, state, batches, metrics,
                                   lambda n, elapsed: n >= steps)
         finally:
             jax.profiler.stop_trace()
@@ -396,16 +313,16 @@ def percentile(values, q):
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
-def result_line(cell, out, correct, setup_s, memory_peak, trace, root):
+def result_line(cell, model, out, correct, setup_s, memory_peak, trace, root):
     import jax
     w = out.window
     devices = jax.devices()
     device = {'platform': devices[0].platform, 'kind': devices[0].device_kind,
               'count': len(devices), 'memory_peak_bytes': int(memory_peak)}
-    images = w['steps'] * w['global_batch']
-    log('window: {} steps, {} images in {:.4f} s; loader wait {:.4f} s; interval median '
-        '{:.3f} ms'.format(w['steps'], images, w['seconds'], w['loader_wait_s'],
-                          1000 * statistics.median(w['intervals'])))
+    log('window: {} steps, {} examples, {} units of {} in {:.4f} s; loader wait {:.4f} s; '
+        'interval median {:.3f} ms'.format(
+            w['steps'], w['examples'], w['units'], model.THROUGHPUT, w['seconds'],
+            w['loader_wait_s'], 1000 * statistics.median(w['intervals'])))
     metrics = {}
     line = {'correct': bool(correct), 'attempted': w['steps'], 'failed': out.failed}
     if trace:
@@ -424,7 +341,7 @@ def result_line(cell, out, correct, setup_s, memory_peak, trace, root):
         line['breakdown'] = out.trace['breakdown']
     else:
         end_to_end = {
-            'images_per_s': images / w['seconds'],
+            model.THROUGHPUT: w['units'] / w['seconds'],
             'step_interval_p95_ms': 1000 * percentile(w['intervals'], 95),
             'setup_s': setup_s,
         }

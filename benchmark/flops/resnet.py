@@ -36,3 +36,8 @@ def forward_flops_per_image(model, image_size):
 
 def train_flops_per_image(model, image_size):
     return 3 * forward_flops_per_image(model, image_size)
+
+
+def train_flops_per_example(config):
+    """Training FLOPs of one image of the configuration's size."""
+    return train_flops_per_image(config['model'], config['image_size'])

@@ -4,8 +4,11 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric sits in a file of its own:
 
 - ``benchmark/configs/<config>.json``: the configuration as it is run; its
-  ``store`` names the generator ``benchmark/stores/<store>.py`` and its
-  ``model.arch`` the FLOP count ``benchmark/flops/<arch>.py``;
+  ``store`` names ``benchmark/stores/<store>.py`` (the generator, the feed
+  and the feed's check) and its ``model.arch`` both
+  ``benchmark/models/<arch>.py`` (the program's state and step, the batch
+  the step takes, the plain reference and the throughput it reports) and
+  ``benchmark/flops/<arch>.py`` (the FLOP count);
 - ``benchmark/traffic/<traffic>.json``: batch, pool and shuffling;
 - ``benchmark/metrics/<metric>.py``: the reader of one per-layer metric.
 
@@ -77,6 +80,11 @@ def metric_reader(metric, root=ROOT):
 
 def store_path(config, root=ROOT):
     return os.path.join(root, 'benchmark', 'stores', config['store'] + '.py')
+
+
+def model_module(config, root=ROOT):
+    return load_module(os.path.join(root, 'benchmark', 'models',
+                                    config['model']['arch'] + '.py'))
 
 
 def flops_module(config, root=ROOT):

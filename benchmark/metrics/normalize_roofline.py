@@ -41,7 +41,7 @@ def reduce(record):
             path.add(op)
     seconds = sum(trace['op_seconds'][op] for op in path)
     rows = window['global_batch'] // window['chips']
-    size = window['image_size']
+    size = record['config']['image_size']
     bytes_per_call = rows * size * size * 3 * (1 + 2)
     least = calls * bytes_per_call / record['peaks']['hbm_bytes_per_s']
     return 100.0 * least / seconds

@@ -35,56 +35,35 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TRAINING = ('loss_gap', 'loss1_gap', 'grad1_gap', 'change_gap', 'grad1_worst_gap',
             'change_worst_gap', 'grad1_diff', 'change_diff')
-FEED = ('pixel_gap', 'pixel_errors', 'label_errors')
 VARIANTS = {'control_fp8': {'quant': 'fp8'}, 'fault_half_batch': {'half_batch': True}}
 
 
 def control_step(config):
-    """A factory with ``make_train_step``'s arguments whose jitted step is
-    the control: the reference's step computed in fp8, on the program's
-    state, flipped as the program flips (``preprocess_seed`` and the step
-    counter)."""
-
-    def factory(preprocess_fn=None, preprocess_seed=0, donate=True):
-        import jax
-
-        from benchmark import reference
-        model = reference.step_model(config['model'], config['optimizer'])
-
-        def step(state, images, labels):
-            key = jax.random.fold_in(jax.random.key(preprocess_seed), state.step)
-            params, trace, loss = reference.sgd_step(
-                state.params, state.opt_state[0].trace, images, labels, key, model, quant='fp8')
-            opt_state = (state.opt_state[0]._replace(trace=trace),) + tuple(state.opt_state[1:])
-            return (state.replace(step=state.step + 1, params=params, opt_state=opt_state),
-                    {'loss': loss})
-
-        return jax.jit(step, donate_argnums=(0,) if donate else ())
-
-    return factory
+    """The factory of the control's step, which the configuration's model
+    module (``benchmark/models/<arch>.py``) puts in the program's place."""
+    from benchmark import manifest
+    return manifest.model_module(config, ROOT).control_step(config)
 
 
 def readings_of(variants):
     """The ``readings`` callback of ``cell.run`` for ``variants``."""
 
-    def readings(config, seed, program, ref, ref_batches, delivered_images, ref_images,
-                 delivered_labels, ref_labels, device, store, store_module, ids):
+    def readings(config, seed, program, ref, ref_batches, delivered, device, store,
+                 store_module, model):
         import numpy as np
 
-        from benchmark import cell, reference
+        from benchmark import gaps
         out = {}
         for name in variants:
-            other = reference.train(config, seed, ref_batches, device=device, **VARIANTS[name])
-            numbers = cell.compare(other, ref, ref_images, ref_images, ref_labels, ref_labels)
+            other = model.reference(config, seed, ref_batches, device, **VARIANTS[name])
+            numbers = gaps.training(other, ref)
             out[name] = {k: numbers[k] for k in TRAINING}
             out[name]['leaves'] = [numbers['_grad1_leaf'], numbers['_change_leaf']]
-        moved = cell.compare(program, ref, np.roll(delivered_images, 1, axis=0), ref_images,
-                             (delivered_labels + 1) % config['model']['num_classes'], ref_labels)
-        out['fault_wrong_record'] = {k: moved[k] for k in FEED}
+        moved = dict(delivered, image=np.roll(delivered['image'], 1, axis=0),
+                     label=(delivered['label'] + 1) % config['model']['num_classes'])
+        out['fault_wrong_record'] = store_module.check(store, config, moved)[0]
         for fault in store_module.FAULTS:
-            images, _ = store_module.reference(store, ids, config, fault=fault)
-            numbers = cell.compare(program, ref, images, ref_images, ref_labels, ref_labels)
-            out['fault_' + fault] = {k: numbers[k] for k in FEED}
+            out['fault_' + fault] = store_module.check(store, config, delivered, fault=fault)[0]
         return out
 
     return readings

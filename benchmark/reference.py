@@ -287,45 +287,3 @@ def train(config, seed, batches, quant=None, half_batch=False, device=None):
         end = jax.device_get(params)
     change = jax.tree_util.tree_map(lambda a, b: np.float64(a) - b, end, start)
     return {'losses': losses, 'grad1': grad1, 'change': change}
-
-
-# -- the numbers compared -----------------------------------------------------
-
-def leaf_norms(tree):
-    return {'/'.join(p): float(np.linalg.norm(np.asarray(v, np.float64)))
-            for p, v in _paths(tree)}
-
-
-def leaf_gaps(program, reference, floor=1e-3):
-    """The gap between the program's norm of each leaf and the reference's,
-    over the reference's norm of that leaf or of the median leaf, whichever
-    is larger. The median leaf is taken over the leaves the reference moves
-    at all; leaves whose reference norm is under ``floor`` times the median
-    leaf's are left out (nought to rounding: behind a zero-initialised
-    residual scale the first gradient is exactly zero). Returns ``(median
-    gap, worst gap, worst leaf)``."""
-    ref = leaf_norms(reference)
-    got = leaf_norms(program)
-    median = float(np.median([r for r in ref.values() if r > 0]))
-    gaps = {name: abs(got[name] - r) / max(r, median)
-            for name, r in ref.items() if r >= floor * median}
-    worst = max(gaps, key=gaps.get)
-    return float(np.median(list(gaps.values()))), gaps[worst], worst
-
-
-def leaf_difference(program, reference, floor=1e-3):
-    """The median, over the leaves ``leaf_gaps`` keeps, of the norm of the
-    difference between the program's leaf and the reference's, over the
-    reference's norm of that leaf or of the median leaf, whichever is
-    larger."""
-    ref = dict(_paths(reference))
-    got = dict(_paths(program))
-    norms = {k: float(np.linalg.norm(np.asarray(v, np.float64))) for k, v in ref.items()}
-    median = float(np.median([r for r in norms.values() if r > 0]))
-    return float(np.median([
-        float(np.linalg.norm(np.asarray(got[k], np.float64) - np.asarray(ref[k], np.float64)))
-        / max(r, median) for k, r in norms.items() if r >= floor * median]))
-
-
-def loss_gap(program_losses, reference_losses):
-    return max(abs(p - r) / abs(r) for p, r in zip(program_losses, reference_losses))

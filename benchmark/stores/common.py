@@ -36,8 +36,9 @@ def build_store(module_path, config, cache_dir, processes=None):
     """Path of the store for ``config``, built if missing.
 
     ``module_path`` is the generator's file: it defines ``VERSION``,
-    ``schema(config)`` and ``rows(config, start, stop)``, a generator of row
-    dicts that gives the same rows for the same arguments in any process."""
+    ``records(config)``, the store's count of records, ``schema(config)``
+    and ``rows(config, start, stop)``, a generator of row dicts that gives
+    the same rows for the same arguments in any process."""
     from benchmark.manifest import load_module
     from petastorm_tpu.etl.dataset_metadata import _write_dataset_metadata, load_row_groups
     module = load_module(module_path)
@@ -48,8 +49,8 @@ def build_store(module_path, config, cache_dir, processes=None):
     os.makedirs(cache_dir, exist_ok=True)
     partial = path + '.partial'
     shutil.rmtree(partial, ignore_errors=True)
-    images = config['images']
-    bounds = list(range(0, images, ROWS_PER_CHUNK)) + [images]
+    records = module.records(config)
+    bounds = list(range(0, records, ROWS_PER_CHUNK)) + [records]
     tasks = [(module_path, config, lo, hi, os.path.join(partial, 'chunk{:05d}'.format(i)))
              for i, (lo, hi) in enumerate(zip(bounds[:-1], bounds[1:]))]
     processes = processes or min(len(tasks), os.cpu_count() or 1)

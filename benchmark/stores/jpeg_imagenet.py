@@ -24,6 +24,8 @@ import zlib
 
 import numpy as np
 
+from benchmark.stores import image_feed
+
 VERSION = 'v2'
 
 #: what ``reference`` can plant in the decode in the program's place, for
@@ -162,6 +164,21 @@ def reference(path, record_ids, config, fault=None):
         images[k] = decode(decoder, image, size, fault)
         labels[k] = zlib.crc32(noun_id.encode()) % num_classes
     return images, labels
+
+
+def records(config):
+    return config['images']
+
+
+def feed(store, config, traffic, seed, global_batch):
+    """The shared image feed (``benchmark/stores/image_feed.py``) with this
+    store's transform."""
+    return image_feed.feed(store, config, traffic, seed, global_batch, transform(config))
+
+
+def check(store, config, delivered, fault=None):
+    """The feed's check (``benchmark/stores/image_feed.py``) against ``reference``."""
+    return image_feed.check(delivered, *reference(store, delivered['record_id'], config, fault))
 
 
 def read_records(path, record_ids, columns):

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmark.stores import jpeg_imagenet
+from benchmark.stores import image_feed, jpeg_imagenet
 from benchmark.stores.jpeg_imagenet import read_records
 
 VERSION = 'v2'
@@ -57,3 +57,18 @@ def reference(path, record_ids, config, fault=None):
                        for r in record_ids])
     labels = np.array([cells[int(r)][1] for r in record_ids], np.int64)
     return images, labels
+
+
+def records(config):
+    return config['images']
+
+
+def feed(store, config, traffic, seed, global_batch):
+    """The shared image feed (``benchmark/stores/image_feed.py``) with this
+    store's transform."""
+    return image_feed.feed(store, config, traffic, seed, global_batch, transform(config))
+
+
+def check(store, config, delivered, fault=None):
+    """The feed's check (``benchmark/stores/image_feed.py``) against ``reference``."""
+    return image_feed.check(delivered, *reference(store, delivered['record_id'], config, fault))

@@ -99,3 +99,12 @@ def test_control_in_the_programs_place_is_not_correct(root):
     config = manifest.load_cell(WORKLOAD, root).config
     line = _run(root, faults={'step': readings.control_step(config)})
     assert not line['correct'], line['checks']
+
+
+def test_readings_report_the_variants_and_the_feed_faults(root):
+    line = _run(root, readings=readings.readings_of(['fault_half_batch']))
+    got = line['readings']
+    assert got['program']['loss_gap'] <= LIMITS['loss_gap']
+    assert got['fault_half_batch']['loss_gap'] > LIMITS['loss_gap']
+    assert got['fault_wrong_record']['pixel_errors'] > 0
+    assert got['fault_wrong_record']['label_errors'] > 0

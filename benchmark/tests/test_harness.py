@@ -61,8 +61,8 @@ def test_reduce_recorded_trace():
     assert any('convolution' in name or 'fusion' in name for name in r['op_seconds'])
     assert all(' = ' in name and '(' not in name and '{' not in name
                for name, _ in r['breakdown']['device_ops'])
-    record = {'trace': r, 'window': {'global_batch': 128, 'chips': 1, 'image_size': 224},
-              'peaks': manifest.peaks('TPU v5 lite')}
+    record = {'trace': r, 'window': {'global_batch': 128, 'chips': 1},
+              'config': {'image_size': 224}, 'peaks': manifest.peaks('TPU v5 lite')}
     roofline = manifest.load_module(os.path.join(ROOT, 'benchmark', 'metrics',
                                                  'normalize_roofline.py')).reduce(record)
     # the kernel with the copies around it: about 150 us a call against 71 us
@@ -134,6 +134,9 @@ def test_every_cell_and_metric_of_the_manifest_resolves():
         cell = manifest.load_cell(workload['name'])
         assert os.path.exists(manifest.store_path(cell.config))
         assert cell.config['name'] == workload['config']
+        # the model module's throughput is one of the cell's end-to-end metrics
+        throughput = manifest.model_module(cell.config).THROUGHPUT
+        assert throughput in [m['name'] for m in cell.end_to_end]
         for metric in cell.per_layer:
             assert callable(manifest.metric_reader(metric))
     assert manifest.peaks('TPU v5 lite')['bf16_flops_per_s'] == 197e12
@@ -193,7 +196,7 @@ def _delivered(store, module, config):
 def test_jpeg_reference_decodes_as_the_program_and_each_fault_apart(tmp_path, tiny_root):
     """The plain decode at the DCT scale and filter the transform states
     gives the program's pixels; each fault planted in it reads far off."""
-    from benchmark import cell
+    from benchmark.stores import image_feed
     from benchmark.stores.common import build_store
     config = manifest.load_json(os.path.join(tiny_root, 'benchmark', 'configs',
                                              'imagenet-jpeg-resnet50.json'))
@@ -205,8 +208,7 @@ def test_jpeg_reference_decodes_as_the_program_and_each_fault_apart(tmp_path, ti
     labels = np.zeros(len(ids), np.int64)
 
     def gap(images):
-        program = {'losses': [1.0], 'grad1': {'w': np.ones(2)}, 'change': {'w': np.ones(2)}}
-        return cell.compare(program, program, delivered, images, labels, labels)['pixel_gap']
+        return image_feed.numbers(delivered, images, labels, labels)['pixel_gap']
 
     plain = gap(module.reference(store, ids, config)[0])
     faults = {f: gap(module.reference(store, ids, config, fault=f)[0]) for f in module.FAULTS}
